@@ -1,7 +1,7 @@
 """Mesh-sharded plan execution: lane-parallel scaling + collective cost.
 
-Two measurements of ``repro.dist.mesh_exec`` on an 8-device
-(host-platform) mesh:
+Two measurements of ``repro.dist.mesh_exec`` on the process's devices
+(under ``JAX_PLATFORMS=cpu``, 8 forced host-platform devices):
 
 1. **Lane-parallel Keccak program scaling.**  The full 24-round
    Keccak-f[1600] plan program over B payload lanes, columns sharded
@@ -43,21 +43,12 @@ import json
 import os
 import time
 
-# 8 host-platform devices; must be set before jax initialises.  When
-# this module is imported by benchmarks/run.py after jax is already
-# live, the sweep degrades to however many devices exist (the modeled
-# scaling numbers only need single-device timings).
-_FLAGS = os.environ.get("XLA_FLAGS", "")
-if "host_platform_device_count" not in _FLAGS:
-    os.environ["XLA_FLAGS"] = (
-        _FLAGS + " --xla_force_host_platform_device_count=8").strip()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from benchmarks.common import row, time_fn
+from benchmarks.common import force_host_devices, row, time_fn
 from repro.core import crossbar as xb
 from repro.core import plan_algebra as pa
 from repro.core import plan_program as pp
@@ -307,6 +298,7 @@ def main():
     ap.add_argument("--quick", action="store_true",
                     help="small shapes only (CI smoke)")
     args = ap.parse_args()
+    force_host_devices()
     run(quick=args.quick)
 
 

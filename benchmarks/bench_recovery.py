@@ -25,10 +25,10 @@ per-lane absorb compute dominates launch overhead — on the host
 platform every "device" shares the same CPU, and with 1-block lanes
 both regimes disappear into fixed dispatch cost.
 
-The mesh needs 8 devices before jax initialises, so ``run`` re-spawns
-this module in a subprocess with ``--xla_force_host_platform_device_
-count=8`` (the ``bench_serving`` pattern).  Results land in
-BENCH_recovery.json (quick: BENCH_recovery_quick.json).
+The mesh is the process's devices: S=8 under ``JAX_PLATFORMS=cpu``
+(forced host-platform devices, set by ``main`` before JAX starts) and
+the chips elsewhere.  Results land in BENCH_recovery.json (quick:
+BENCH_recovery_quick.json).
 
 Usage: PYTHONPATH=src python -m benchmarks.bench_recovery [--quick]
 """
@@ -39,11 +39,11 @@ import argparse
 import hashlib
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
+
+from benchmarks.common import force_host_devices
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OUT_JSON = os.path.join(REPO, "BENCH_recovery.json")
@@ -102,8 +102,9 @@ def bench_inner(iters: int) -> dict:
     from repro.serve.batching import BatchingEngine, BatchingOptions
 
     assert len(jax.devices()) >= SHARDS, (
-        f"need {SHARDS} devices, got {len(jax.devices())} — run via the "
-        "module entry point so XLA_FLAGS is set before jax imports")
+        f"need {SHARDS} devices, got {len(jax.devices())}: on the CPU run "
+        "the module entry point under JAX_PLATFORMS=cpu, which forces "
+        "host devices before JAX starts")
     mesh = Mesh(np.asarray(jax.devices()[:SHARDS]), ("data",))
     payloads = _payloads(LANES)
 
@@ -160,39 +161,14 @@ def bench_inner(iters: int) -> dict:
             "devices": len(jax.devices())}
 
 
-def _spawn_inner(iters: int):
-    """Re-spawn this module with 8 forced host devices (jax must see
-    XLA_FLAGS before import, so the measurement runs in a child)."""
-    out_path = os.path.join(REPO, ".bench_recovery_fragment.json")
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (flags +
-                            " --xla_force_host_platform_device_count=8")
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(REPO, "src"),
-                    env.get("PYTHONPATH", "")) if p)
-    cmd = [sys.executable, "-m", "benchmarks.bench_recovery", "--inner",
-           "--iters", str(iters), "--out", out_path]
-    proc = subprocess.run(cmd, cwd=REPO, env=env, timeout=3600,
-                          capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"recovery subprocess failed (rc={proc.returncode}):\n"
-            f"{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}")
-    print(proc.stdout, end="")
-    with open(out_path) as f:
-        fragment = json.load(f)
-    os.remove(out_path)
-    return fragment
-
-
 def run(quick: bool = False) -> dict:
     import jax
     from benchmarks.common import row
 
     iters = 2 if quick else 8
-    fragment = _spawn_inner(iters)
+    # In this process, on the devices it has: a child started after JAX
+    # is up could not share the chip.
+    fragment = bench_inner(iters)
     replay, whole = fragment["rows"]
     for r in fragment["rows"]:
         row("recovery", regime=r["regime"], p50_ms=r["p50_ms"],
@@ -247,17 +223,8 @@ def run(quick: bool = False) -> dict:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    ap.add_argument("--inner", action="store_true",
-                    help="(internal) run the measurement in-process")
-    ap.add_argument("--iters", type=int, default=20)
-    ap.add_argument("--out", default="")
     args = ap.parse_args()
-    if args.inner:
-        fragment = bench_inner(args.iters)
-        with open(args.out, "w") as f:
-            json.dump(fragment, f, indent=2)
-            f.write("\n")
-        return
+    force_host_devices()
     run(quick=args.quick)
 
 

@@ -22,15 +22,16 @@ interesting quantities are throughput and the fault-regime *ratios*
 Off-TPU the chain starts at einsum (``resilience.default_chain``), so
 the numbers measure the XLA take-fastpath, not Pallas interpret mode.
 
-The full run additionally spawns an 8-device (host-platform) subprocess
-for the **mesh regime**: 10^6 requests through the threaded engine with
-bucket sharding, double-buffered host→device feeds, and the measured
-tuning table — sustained hashes/sec and p50/p99 appended alongside the
-single-device rows.  The benchmark host time-slices its XLA host
-devices across ``host_cores`` physical core(s); the mesh rows record
-that honestly rather than claiming device-parallel wall-clock speedup.
-``--mesh`` runs ONLY the mesh regime in-process (the CI mesh smoke job
-does this under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
+When the process has more than one device, the full run adds the
+**mesh regime**, in the same process: 10^6 requests through the
+threaded engine with bucket sharding, double-buffered host→device
+feeds, and the measured tuning table — sustained hashes/sec and p50/p99
+appended alongside the single-device rows.  On the CPU the devices come
+from ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` set before
+the run; those host devices time-slice ``host_cores`` physical
+core(s), and the mesh rows record that rather than claiming a
+device-parallel speedup.  ``--mesh`` runs ONLY the mesh regime (the CI
+mesh smoke job does this under that flag).
 
 Results land in BENCH_serving.json (quick: BENCH_serving_quick.json so
 CI smoke never clobbers the committed sweep).
@@ -45,8 +46,6 @@ import argparse
 import hashlib
 import json
 import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -337,44 +336,6 @@ def run_mesh(n_requests, out_path=None) -> dict:
     return fragment
 
 
-def _spawn_mesh_subprocess(n_requests):
-    """Run the mesh regime in a fresh interpreter with 8 host devices.
-
-    The parent process initialised jax with a single device, so the
-    8-device mesh regime must run in a subprocess where XLA_FLAGS takes
-    effect before jax import.  Returns the mesh row dict, or None (with
-    a printed warning) if the subprocess fails — the single-device rows
-    are still written either way.
-    """
-    out_path = os.path.join(REPO, ".bench_serving_mesh_fragment.json")
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (flags +
-                            " --xla_force_host_platform_device_count=8")
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(REPO, "src"),
-                    env.get("PYTHONPATH", "")) if p)
-    cmd = [sys.executable, "-m", "benchmarks.bench_serving", "--mesh",
-           "--mesh-requests", str(n_requests), "--mesh-out", out_path]
-    try:
-        proc = subprocess.run(cmd, cwd=REPO, env=env, timeout=3600,
-                              capture_output=True, text=True)
-        if proc.returncode != 0:
-            print(f"# mesh subprocess failed (rc={proc.returncode}):\n"
-                  f"{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}")
-            return None
-        print(proc.stdout, end="")
-        with open(out_path) as f:
-            fragment = json.load(f)
-        os.remove(out_path)
-        return fragment["rows"][0]
-    except (subprocess.TimeoutExpired, OSError, KeyError,
-            json.JSONDecodeError) as e:
-        print(f"# mesh subprocess failed: {e!r}")
-        return None
-
-
 def run(quick: bool = False) -> dict:
     n = 200 if quick else 10_000
     max_batch = 16 if quick else 128
@@ -390,7 +351,14 @@ def run(quick: bool = False) -> dict:
                          fault_rate=0.01, seed=7)
     traced = bench_traced_stages(payloads, max_batch=max_batch, seed=0)
 
-    mesh = None if quick else _spawn_mesh_subprocess(MESH_REQUESTS)
+    # The mesh regime runs in this process, on the devices it already
+    # has: a child started after JAX is up could not share the chip.
+    mesh = None
+    if not quick and len(jax.devices()) > 1:
+        mesh = bench_mesh_regime(MESH_REQUESTS)
+    elif not quick:
+        print("# mesh regime skipped: one device (on the CPU, run under "
+              "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
 
     acceptance = {
         "criterion": "10^4 queued SHA3-256 requests drain bit-exactly vs "
@@ -474,9 +442,9 @@ def main():
     ap.add_argument("--quick", action="store_true",
                     help="small request count (CI smoke)")
     ap.add_argument("--mesh", action="store_true",
-                    help="run ONLY the mesh regime in-process (run under "
+                    help="run ONLY the mesh regime (on the CPU, under "
                          "XLA_FLAGS=--xla_force_host_platform_device_"
-                         "count=8; the full run spawns this itself)")
+                         "count=8)")
     ap.add_argument("--mesh-out", default=None,
                     help="write the mesh JSON fragment here")
     ap.add_argument("--mesh-requests", type=int, default=None,

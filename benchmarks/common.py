@@ -2,10 +2,24 @@
 
 from __future__ import annotations
 
+import os
 import time
 
 import jax
 import numpy as np
+
+
+def force_host_devices(n: int = 8) -> None:
+    """Under ``JAX_PLATFORMS=cpu``, ask XLA for ``n`` host devices so
+    the mesh benchmarks have a mesh in this process.  Takes effect only
+    before JAX creates its backend; on an accelerator the devices are
+    the chips and nothing is set."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}").strip()
 
 
 def time_fn(fn, *args, iters=20, warmup=3):

@@ -12,6 +12,8 @@ import sys
 import time
 
 import benchmarks
+from benchmarks.common import force_host_devices
+from repro.launch.compile_cache import use_compile_cache
 
 PREFIX = "bench_"
 
@@ -26,6 +28,8 @@ def discover() -> list[str]:
 
 
 def main() -> None:
+    force_host_devices()
+    use_compile_cache()
     failed = 0
     for modname in discover():
         name = modname[len(PREFIX):]

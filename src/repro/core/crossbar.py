@@ -431,19 +431,21 @@ def unpin_plan(plan: "PermutePlan") -> int:
     return removed
 
 
-def _is_concrete(x) -> bool:
-    """Concrete array outside any live trace.
+def is_cacheable(*arrays) -> bool:
+    """No live trace, and every operand (``None`` allowed) concrete.
 
-    The trace-state check matters: under omnistaging, jnp ops run inside
-    a jit trace are staged and return tracers even when every operand is
-    concrete, so a schedule compiled there is trace-local — caching it
-    (or calling ``int()`` on its count) would leak tracers out of the
-    trace.  Cache *lookups* for concrete plans are still allowed under a
-    trace (see compile_plan): a stored schedule is concrete and folds
-    into the trace as constants.
+    The condition for *storing* into a cross-call cache keyed on these
+    arrays, and for branching on their values on the host.  The trace-state check matters: under omnistaging, jnp ops
+    run inside a jit/vmap/grad trace are staged and return tracers even
+    when every operand is concrete, so a schedule compiled there is
+    trace-local — caching it (or calling ``int()`` on its count) would
+    leak tracers out of the trace.  ``ensure_compile_time_eval`` counts
+    as no trace: its ops evaluate eagerly.  Cache *lookups* for concrete
+    plans are still allowed under a trace (see compile_plan): a stored
+    schedule is concrete and folds into the trace as constants.
     """
-    return (jax.core.trace_state_clean() and x is not None
-            and not isinstance(x, jax.core.Tracer))
+    return jax.core.trace_ctx.is_top_level() and all(
+        a is None or not isinstance(a, jax.core.Tracer) for a in arrays)
 
 
 def _is_concrete_array(x) -> bool:
@@ -519,7 +521,7 @@ def compile_plan(plan: PermutePlan, *, block_o: int = 128,
     # Storing (and the int() demotion) additionally require a clean trace
     # state — under omnistaging the schedule arrays above are tracers
     # inside a jit trace even for concrete plans.
-    cacheable = keyable and jax.core.trace_state_clean()
+    cacheable = keyable and is_cacheable()
     num_active: Union[int, Array] = num
     if cacheable:
         num_active = int(num)
@@ -936,7 +938,7 @@ def lift_gf2_k(plan: PermutePlan) -> PermutePlan:
             width * plan.n_in, width * plan.k)
         lifted = scatter_plan(bit_idx, width * plan.n_out, semiring=GF2)
 
-    if keyable and jax.core.trace_state_clean():
+    if keyable and is_cacheable():
         _integrity.LIFT_GUARD.seal(key, (lifted.idx, lifted.weights))
         _LIFT_CACHE[key] = (lifted, plan.idx, plan.weights)
         while len(_LIFT_CACHE) > _LIFT_CACHE_CAPACITY:

@@ -95,25 +95,13 @@ def clear_plan_cache() -> None:
     _PLAN_CACHE_STATS.update(hits=0, misses=0)
 
 
-def _concrete(*arrays) -> bool:
-    """True when every operand is a concrete array AND no trace is live.
-
-    Inside a jit trace, jnp ops on concrete operands are staged as
-    constants and return tracers — a plan built there is trace-local and
-    must never enter the cross-call memo (it would leak tracers), and
-    value-dependent simplifications must not branch on it.
-    """
-    return jax.core.trace_state_clean() and all(
-        a is None or not isinstance(a, jax.core.Tracer) for a in arrays)
-
-
 def _memo(op: str, operands: tuple, static: tuple, build):
     """Memoised plan construction keyed on operand identity + static args.
 
     ``operands`` are the arrays whose identity keys the entry (None allowed);
     traced operands bypass the cache entirely.
     """
-    if not _concrete(*operands):
+    if not xb.is_cacheable(*operands):
         _PLAN_CACHE_STATS["misses"] += 1
         return build()
     key = (op, static, tuple(id(a) for a in operands))
@@ -215,13 +203,13 @@ def is_identity(plan: xb.PermutePlan) -> bool:
     """True iff the plan is provably (concretely) the identity."""
     if plan.n_in != plan.n_out or plan.k != 1:
         return False
-    if not _concrete(plan.idx, plan.weights):
+    if not xb.is_cacheable(plan.idx, plan.weights):
         return False
     if plan.weights is not None and not bool(
             (np.asarray(plan.weights) == 1.0).all()):
         return False
     g = to_gather(plan)
-    if not _concrete(g.idx):
+    if not xb.is_cacheable(g.idx):
         return False
     return bool(np.array_equal(np.asarray(g.idx[:, 0]),
                                np.arange(plan.n_in)))
@@ -329,7 +317,7 @@ def compact_selects(plan: xb.PermutePlan) -> xb.PermutePlan:
     through unchanged (compaction is value-dependent).
     """
     g = to_gather(plan)
-    if not _concrete(g.idx, g.weights):
+    if not xb.is_cacheable(g.idx, g.weights):
         return g
 
     def build():
@@ -602,7 +590,7 @@ def _simplify_ops(ops: list) -> list:
     for op in ops:
         if (op.kind == "gather" and op.mask is None
                 and op.idx.shape[0] == op.n
-                and _concrete(op.idx)
+                and xb.is_cacheable(op.idx)
                 and bool(np.array_equal(np.asarray(op.idx),
                                         np.arange(op.n)))):
             continue

@@ -428,64 +428,93 @@ def encode_steps(program: PlanProgram) -> np.ndarray:
     return np.asarray(rows, np.int32)
 
 
-def _build_exec(program: PlanProgram, n_pad: int, interpret: bool):
-    """Jitted megakernel closure for one (program, geometry) pair.
+def _encode_plans(program: PlanProgram, ppk) -> tuple:
+    """The program's plans as the kernel's flat select-entry list.
 
-    Control information is encoded once here: the step stream, the
-    RAGGED flat plan table (every plan's select columns concatenated
-    along one axis, one (n_pad,) row per column, with per-plan
-    offset/count vectors — a k=128 S-box decode no longer pads a dozen
-    k<=2 routing plans to its width), the per-plan semiring fold flags,
-    the ragged weight rows (only weighted plans contribute; offset -1
-    marks the rest), and the (optionally strided) constants table.
+    Every live select ``idx[i, j]`` (0 <= src < n) becomes one
+    ``(dst=i, src, weight)`` triple (weight 1 for unweighted plans);
+    DROP and out-of-range selects contribute nothing, so they are not
+    encoded.  Each plan's run starts on an HBM_ALIGN-word boundary and
+    the list carries one ENTRY_CHUNK of tail padding, so the kernel's
+    fixed-size chunk DMAs stay in bounds.  Returns (entries, meta) with
+    meta = per plan (word offset, entry count, GF(2) XOR fold, 0).
+    """
+    runs, meta, off = [], [], 0
+    for plan in program.plans:
+        idx = np.asarray(plan.idx, np.int32)
+        live = (idx >= 0) & (idx < program.n)
+        dst, col = np.nonzero(live)
+        w = (np.ones(dst.shape, np.int32) if plan.weights is None
+             else np.asarray(plan.weights, np.int32)[dst, col])
+        run = np.stack([dst.astype(np.int32), idx[dst, col], w],
+                       axis=1).reshape(-1)
+        run = np.pad(run, (0, (-run.size) % ppk.HBM_ALIGN))
+        meta.append((off, dst.size, int(_plan_fold(plan) == "xor"), 0))
+        runs.append(run)
+        off += run.size
+    runs.append(np.zeros(ppk.ENTRY_WORDS * ppk.ENTRY_CHUNK, np.int32))
+    meta = np.asarray(meta or [(0, 0, 0, 0)], np.int32).reshape(-1)
+    return np.concatenate(runs), meta
+
+
+def _encode_consts(program: PlanProgram, n_pad: int, ppk) -> np.ndarray:
+    """Constants lane-transposed into (blocks, n_pad, 128): constant
+    row ``c`` is lane ``c % 128`` of block ``c // 128``, so a step reads
+    it as a per-row column without a transpose on the chip."""
+    consts = (np.zeros((1, program.n), np.int32) if program.consts is None
+              else np.asarray(program.consts, np.int32))
+    c, n = consts.shape
+    padded = np.zeros((c + (-c) % ppk.LANES, n_pad), np.int32)
+    padded[:c, :n] = consts
+    return np.ascontiguousarray(
+        padded.reshape(-1, ppk.LANES, n_pad).transpose(0, 2, 1))
+
+
+def encode_program(program: PlanProgram, n_pad: int) -> tuple:
+    """The megakernel's operands for one program at ``n_pad`` rows.
+
+    Control information is encoded once here: the step stream (padded
+    to whole SMEM chunks), every plan's live selects as a flat
+    (dst, src, weight) entry list with per-plan offset/count/fold
+    metadata — the work of a PERMUTE is its live selects, not rows x k
+    of DROP padding — and the lane-transposed constants table.
+    Returns ``(control, static)``: the control arrays the kernel takes
+    after the state, and its static keyword arguments.
     """
     from repro.kernels import plan_program_kernel as ppk  # lazy: kernels opt.
 
-    # The step-stream opcodes index the kernel's switch branch list;
-    # the two orderings must never drift apart.
+    # The step-stream opcodes index the kernel's op bodies; the two
+    # orderings must never drift apart.
     assert ppk.OPCODES == OPS, (
         f"kernel opcode table {ppk.OPCODES} drifted from the IR's op "
         f"order {OPS}")
 
-    idx_rows, w_rows = [], []
-    koff, kcnt, folds, woff = [], [], [], []
-    for plan in program.plans:
-        idx = np.asarray(plan.idx, np.int32)
-        idx = np.pad(idx, ((0, n_pad - idx.shape[0]), (0, 0)),
-                     constant_values=pa.DROP)
-        koff.append(len(idx_rows))
-        kcnt.append(idx.shape[1])
-        idx_rows.extend(idx.T)
-        folds.append(1 if _plan_fold(plan) == "xor" else 0)
-        if plan.weights is None:
-            woff.append(-1)
-        else:
-            w = np.asarray(plan.weights, np.int32)
-            w = np.pad(w, ((0, n_pad - w.shape[0]), (0, 0)))
-            woff.append(len(w_rows))
-            w_rows.extend(w.T)
-    plan_tbl = jnp.asarray(
-        np.stack(idx_rows) if idx_rows
-        else np.zeros((1, n_pad), np.int32))
-    koff_op = jnp.asarray(np.asarray(koff or [0], np.int32))
-    kcnt_op = jnp.asarray(np.asarray(kcnt or [0], np.int32))
-    folds_op = jnp.asarray(np.asarray(folds or [0], np.int32))
-    woff_op = jnp.asarray(np.asarray(woff or [-1], np.int32))
-    w_flat = jnp.asarray(np.stack(w_rows)) if w_rows else None
-    consts_np = (np.zeros((1, program.n), np.int32)
-                 if program.consts is None else program.consts)
-    consts_op = _pad_axis(jnp.asarray(consts_np, jnp.int32), n_pad, 1)
-    steps_op = jnp.asarray(encode_steps(program))
+    steps = encode_steps(program)
+    n_steps = steps.shape[0]
+    words = np.zeros((n_steps + (-n_steps) % ppk.STEP_CHUNK,
+                      ppk.STEP_WORDS), np.int32)
+    words[:n_steps, :steps.shape[1]] = steps
+    entries, meta = _encode_plans(program, ppk)
+    control = (words.reshape(-1), entries, meta,
+               _encode_consts(program, n_pad, ppk))
+    static = dict(n_steps=n_steps, n_regs=program.n_regs,
+                  rounds=program.rounds, const_stride=program.const_stride)
+    return control, static
 
-    call = functools.partial(
-        ppk.plan_program_pallas,
-        n_valid=program.n, n_regs=program.n_regs, rounds=program.rounds,
-        const_stride=program.const_stride, interpret=interpret)
 
-    @jax.jit
+def _build_exec(program: PlanProgram, n_pad: int, interpret: bool):
+    """Megakernel closure for one (program, geometry) pair: the control
+    arrays go to the device once and ride as arguments of one jitted
+    launch (not as constants folded into the executable)."""
+    from repro.kernels import plan_program_kernel as ppk  # lazy: kernels opt.
+
+    control, static = encode_program(program, n_pad)
+    control = tuple(jnp.asarray(c) for c in control)
+    launch = jax.jit(functools.partial(ppk.plan_program_pallas,
+                                       interpret=interpret, **static))
+
     def run(xp):
-        return call(xp, steps_op, plan_tbl, koff_op, kcnt_op, folds_op,
-                    w_flat, woff_op, consts_op)
+        return launch(xp, *control)
 
     return run
 
@@ -493,11 +522,12 @@ def _build_exec(program: PlanProgram, n_pad: int, interpret: bool):
 def _run_megakernel(program: PlanProgram, x2: Array,
                     interpret: Optional[bool]) -> Array:
     global _PROGRAM_LAUNCHES, _PASSES_AVOIDED
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    from repro.kernels import plan_program_kernel as ppk  # lazy: kernels opt.
+    from repro.kernels.ops import default_interpret
+    interpret = default_interpret(interpret)
     n, d = x2.shape
-    n_pad = n + (-n) % 8
-    d_pad = d + (-d) % 128
+    n_pad = n + (-n) % ppk.ROW_TILE
+    d_pad = d + (-d) % ppk.LANES
     key = (id(program), n_pad, d_pad, str(x2.dtype), bool(interpret))
     hit = _EXEC_CACHE.get(key)
     cache_hit = hit is not None and hit[0] is program
@@ -524,7 +554,7 @@ def _run_megakernel(program: PlanProgram, x2: Array,
     with _COUNT_LOCK:
         _PROGRAM_LAUNCHES += 1
         _PASSES_AVOIDED += program.passes
-    xp = _pad_axis(_pad_axis(x2, 8, 0), 128, 1)
+    xp = _pad_axis(_pad_axis(x2, ppk.ROW_TILE, 0), ppk.LANES, 1)
     with _obs.span("program_launch", program=program.name,
                    passes=program.passes, n=n, d=d,
                    exec_cache_hit=cache_hit):

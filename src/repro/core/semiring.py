@@ -217,23 +217,26 @@ def gf2k_mul_limbs(a, b, width: int, poly: int):
     and weight-fold use only, never a payload hot path.
     """
     if isinstance(a, jax.Array) or isinstance(b, jax.Array):
-        a = jnp.asarray(a, jnp.int32)
-        b = jnp.asarray(b, jnp.int32)
-        where = jnp.where
-        zeros = jnp.zeros_like
-    else:
-        a = np.asarray(a, np.int32)
-        b = np.asarray(b, np.int32)
-        where = np.where
-        zeros = np.zeros_like
-    acc = zeros(a * 0 + b * 0)   # broadcast shape
+        # One compiled program, not ``width`` x 8 eager dispatches.
+        return _gf2k_mul_limbs_jit(jnp.asarray(a, jnp.int32),
+                                   jnp.asarray(b, jnp.int32), width, poly)
+    return _gf2k_mul_limbs(np.asarray(a, np.int32), np.asarray(b, np.int32),
+                           width, poly)
+
+
+def _gf2k_mul_limbs(a, b, width: int, poly: int):
+    acc = (a * 0 + b * 0)   # zeros of the broadcast shape
     cur = a + acc
+    where = jnp.where if isinstance(acc, jax.Array) else np.where
     for bit in range(width):
         r, s = divmod(bit, 8)
         bbit = (b[..., r] >> s) & 1
         acc = acc ^ where(bbit[..., None] != 0, cur, 0)
         cur = gf2k_xtime_limbs(cur, width, poly)
     return acc
+
+
+_gf2k_mul_limbs_jit = jax.jit(_gf2k_mul_limbs, static_argnums=(2, 3))
 
 
 def gf2k_to_limbs(v: int, width: int) -> np.ndarray:

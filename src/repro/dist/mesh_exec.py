@@ -42,7 +42,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs as _obs
@@ -329,14 +328,14 @@ def sharded_apply_fn(plan: xb.PermutePlan, mesh: Mesh, *,
         trailing = (None,) * (x.ndim - 1)
         ctrl_spec = P(None, axis, None, None)
         if weights is None:
-            fn = shard_map(lambda i, xv: body(i, None, xv), mesh=mesh,
-                           in_specs=(ctrl_spec, P(axis, *trailing)),
-                           out_specs=P(axis, *trailing))
+            fn = jax.shard_map(lambda i, xv: body(i, None, xv), mesh=mesh,
+                               in_specs=(ctrl_spec, P(axis, *trailing)),
+                               out_specs=P(axis, *trailing))
             return fn(idx, x)
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(ctrl_spec, ctrl_spec,
-                                 P(axis, *trailing)),
-                       out_specs=P(axis, *trailing))
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(ctrl_spec, ctrl_spec,
+                                     P(axis, *trailing)),
+                           out_specs=P(axis, *trailing))
         return fn(idx, weights, x)
 
     return jax.jit(apply)
@@ -441,15 +440,15 @@ def sharded_apply_naive_fn(plan: xb.PermutePlan, mesh: Mesh, *,
                 f"!= plan n_in {n_in}")
         trailing = (None,) * (x.ndim - 1)
         if weights is None:
-            fn = shard_map(lambda i, xv: body(i, None, xv), mesh=mesh,
-                           in_specs=(P(axis, None, None),
+            fn = jax.shard_map(lambda i, xv: body(i, None, xv), mesh=mesh,
+                               in_specs=(P(axis, None, None),
+                                         P(axis, *trailing)),
+                               out_specs=P(axis, *trailing))
+            return fn(idx, x)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(axis, None, None), P(axis, None, None),
                                      P(axis, *trailing)),
                            out_specs=P(axis, *trailing))
-            return fn(idx, x)
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(axis, None, None), P(axis, None, None),
-                                 P(axis, *trailing)),
-                       out_specs=P(axis, *trailing))
         return fn(idx, weights, x)
 
     return jax.jit(apply)
@@ -511,6 +510,6 @@ def sharded_program_fn(program, mesh: Mesh, *, axis: str = "data",
                               pass_backend=pass_backend,
                               interpret=interpret)
 
-    body = shard_map(local, mesh=mesh, in_specs=P(None, axis),
-                     out_specs=P(None, axis))
+    body = jax.shard_map(local, mesh=mesh, in_specs=P(None, axis),
+                         out_specs=P(None, axis))
     return jax.jit(body)

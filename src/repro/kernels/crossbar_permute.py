@@ -68,6 +68,16 @@ def _onehot_tile(idx_blk, w_blk, o_base, n_base, bo, bn, mode, compute_dtype):
     return tile
 
 
+def _exact_dot(tile, x):
+    """One-hot tile times payload, accumulated in f32.  An f32 payload
+    asks for full-precision passes: the MXU's default rounds f32
+    operands to bf16, which would not route an f32 value exactly."""
+    precision = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot(tile, x, precision=precision,
+                       preferred_element_type=jnp.float32)
+
+
 def _kernel(idx_ref, x_ref, *refs, mode, weighted, use_merge,
             bo, bn, n_tiles, n_in_valid, fold_mod2=False):
     """One grid step of the crossbar contraction."""
@@ -100,9 +110,7 @@ def _kernel(idx_ref, x_ref, *refs, mode, weighted, use_merge,
     tile = _onehot_tile(idx_blk, w_blk, o_i * bo, n_i * bn, bo, bn, mode,
                         compute_dtype)
 
-    acc_ref[...] += jax.lax.dot(
-        tile, x_blk.astype(compute_dtype),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _exact_dot(tile, x_blk.astype(compute_dtype))
 
     # Coverage (unweighted hit count per output row) for merge semantics.
     if mode == "gather":
@@ -258,9 +266,7 @@ def _sparse_kernel(po_ref, pn_ref, act_ref, idx_ref, x_ref, *refs,
                          else jnp.float32)
         tile = _onehot_tile(idx_blk, w_blk, o_cur * bo, pn_ref[p] * bn,
                             bo, bn, mode, compute_dtype)
-        acc_ref[...] += jax.lax.dot(
-            tile, x_blk.astype(compute_dtype),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] += _exact_dot(tile, x_blk.astype(compute_dtype))
 
     if guard:
         pl.when(is_active)(_accumulate)

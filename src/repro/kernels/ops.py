@@ -64,7 +64,10 @@ def _surface_kernel_errors(kernel: str, plan):
         ) from e
 
 
-def _default_interpret(interpret):
+def default_interpret(interpret=None) -> bool:
+    """Pallas interpret mode for a kernel launch: an explicit choice
+    wins; otherwise compiled on a TPU backend and interpreted anywhere
+    else."""
     if interpret is not None:
         return interpret
     return jax.default_backend() != "tpu"
@@ -129,7 +132,7 @@ def crossbar_permute(plan, x, *, merge=None, interpret=None,
     """
     from repro.core import crossbar as xb  # avoid import cycle at load time
 
-    interpret = _default_interpret(interpret)
+    interpret = default_interpret(interpret)
     fold_mod2 = _semiring_fold(plan)
     n_in, n_out = plan.n_in, plan.n_out
     mode = "gather" if plan.mode == xb.GATHER else "scatter"
@@ -176,7 +179,7 @@ def crossbar_permute_sparse(plan, x, *, compiled=None, interpret=None,
     """
     from repro.core import crossbar as xb  # avoid import cycle at load time
 
-    interpret = _default_interpret(interpret)
+    interpret = default_interpret(interpret)
     fold_mod2 = _semiring_fold(plan)
     n_in, n_out = plan.n_in, plan.n_out
     mode = "gather" if plan.mode == xb.GATHER else "scatter"
@@ -227,7 +230,7 @@ def crossbar_permute_sparse(plan, x, *, compiled=None, interpret=None,
 
 def fused_vcompress(mask, x, *, tail="zero", interpret=None, block_d=128):
     """Fused mask->transform->crossbar compress. x: (N, D) -> (N, D)."""
-    interpret = _default_interpret(interpret)
+    interpret = default_interpret(interpret)
     orig_dtype = x.dtype
     x = _as_f32_payload(x)
     d = x.shape[1]
@@ -310,7 +313,7 @@ def bits_roundtrip(x, width, *, axis=0):
 def moe_route_transform(expert_ids, *, num_experts, capacity,
                         interpret=None, block_t=256):
     """Fused MoE position/destination transform. (T,K) -> (pos, dest)."""
-    interpret = _default_interpret(interpret)
+    interpret = default_interpret(interpret)
     t = expert_ids.shape[0]
     idp = _pad_to(expert_ids, block_t, 0, value=DROP)
     pos, dest = moe_route_transform_pallas(

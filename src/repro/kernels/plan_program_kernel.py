@@ -7,53 +7,40 @@ whole workload.  A crypto permutation is the opposite regime: dozens of
 interleaved with elementwise arithmetic, where the cost is not the
 FLOPs but the HBM round-trip of the state between every step.
 
-This kernel inverts the loop: the state is DMA'd into VMEM **once**, a
-register file of ``(n, D)`` buffers lives entirely on-chip, and the
-program executes as a **bytecode VM** over the resident registers:
+This kernel inverts the loop: each lane block of the state is DMA'd
+into VMEM **once**, a register file of ``(n, lanes)`` buffers lives
+entirely on-chip, and the program executes as a **bytecode VM** over
+the resident registers:
 
-* the step stream (opcode, register wiring, plan/const slot — all
-  int32 rows) rides along as control operands, exactly like the sparse
-  kernel's scalar-prefetched schedule;
-* a ``lax.scan`` walks one round's steps, dispatching each through a
-  ``lax.switch`` whose branches implement the ops (in-VMEM k-select
-  gather-fold for PERMUTE — integer XOR for GF(2), so bit states never
-  touch the f32 datapath and the MXU's 2^24 exactness bound does not
-  apply; VPU elementwise for the rest);
+* the step stream is program data in HBM, walked in SMEM chunks by a
+  ``fori_loop``: each step's eight int32 words (opcode, register
+  wiring, plan/const slot) are scalar reads, and the opcode selects
+  its op body with ``pl.when`` — every body is compiled once, however
+  many steps or rounds the program has;
+* a PERMUTE walks its plan's **select entries** — the flat list of
+  ``(dst row, src row, weight)`` triples of the plan's live selects,
+  DMA'd into SMEM a chunk at a time — and folds each gathered source
+  row into an accumulator register: integer XOR of bit 0 for GF(2)
+  (so bit states never touch the f32 datapath and the MXU's 2^24
+  exactness bound does not apply), wrapping add for REAL.  A row load
+  at a scalar offset is the gather the TPU's vector unit offers; the
+  work is the plan's live entries, never ``rows x k`` of DROP padding;
+* the elementwise ops (XOR/AND/ANDN/ADD/ROTLV/XOR_CONST/EQ_CONST) run
+  over the registers in row tiles; a constant row reaches them as a
+  ``(rows, 1)`` column cut out of a lane-transposed constants block
+  (constant ``c`` is lane ``c % 128`` of block ``c // 128``), DMA'd
+  into VMEM when a step first needs that block;
 * a ``fori_loop`` supplies the trip count, with per-round constants
-  indexed as ``const + round * const_stride``;
-* the result is written back once at the end.
+  indexed as ``const + round * const_stride``.
 
-The VM structure is not a stylistic choice: each op's body is compiled
-exactly once no matter how many steps or rounds the program has.  The
-obvious alternative — unrolling the steps at trace time — hands XLA a
-deep chain of fan-out gathers whose fusion cost grows *exponentially*
-(measured on CPU: 4 unrolled Keccak rounds blow a minutes-long compile
-budget that the VM covers in under a second, `optimization_barrier`
-notwithstanding).  It is also the better fixed-latency story: every
-step runs the same dispatch code, so the launch's schedule is a
-function of the program stream alone and never of payload values —
-every branch of the switch is fixed-shape, and the switch index is
-program data.
+The unit of VMEM residency is one lane block: a grid over the payload
+(lane) axis runs the complete program on each block in turn.  Lanes
+are independent by construction, so the block width changes no result;
+``lane_block`` picks the widest block whose register file fits VMEM.
 
-Plan tables use a RAGGED flat layout: the select columns of every plan
-are concatenated along one axis (``plan_tbl``: (K_total, n_pad), one
-row per select column) with per-plan offset/count vectors, and the
-PERMUTE branch runs a ``fori_loop`` over exactly that plan's count.
-The former layout stacked every plan to a common ``k_max`` — fine when
-plans share a width, quadratically wasteful when one k=128 S-box
-decode rides beside a dozen k<=2 routing plans (the AES-GCM program's
-shape: the stacked table would be ~5x the flat one, and every k=1 step
-would gather 128 columns).  Weights are ragged the same way
-(``w_flat`` + per-plan offset, -1 for unweighted plans), so one
-weighted plan no longer forces a full-size weight table for all.  The
-loop bound is *program* data (scalar-prefetch class, payload-
-independent), so fixed latency per program is preserved.
-
-Everything here targets states of a few thousand rows at payload
-widths up to a few hundred lanes — (1600, 128) int32 is 800 KB, far
-under VMEM — so a single un-gridded launch with whole-array operands
-is the right shape.  Wider payloads shard lanes *outside* the kernel
-(they are independent by construction).
+The schedule is a function of the program stream alone and never of
+payload values: every loop bound is program data, so one program's
+launch has a fixed latency per lane block.
 """
 
 from __future__ import annotations
@@ -63,17 +50,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DROP = -1
-
-# Opcode numbering: the switch branch list below is BUILT from this
-# tuple, and core.plan_program's step-stream encoder asserts its OPS
+# Opcode numbering: the op bodies below are dispatched on this tuple's
+# indices, and core.plan_program's step-stream encoder asserts its OPS
 # order matches it — insert or reorder an op in one place without the
 # other and programs fail loudly at build time, never silently.
 # ("eq_const" appended last so pre-existing encoded streams keep their
 # numbering.)
 OPCODES = ("permute", "xor", "and", "andn", "add", "rotlv", "xor_const",
            "eq_const")
+
+# Encoded layouts.  HBM arrays are 1-D and DMA'd in chunks whose start
+# and size are multiples of 1024 words (the 1-D HBM tiling).
+STEP_WORDS = 8           # (op, dst, a, b, plan, const, 0, 0)
+STEP_CHUNK = 128         # steps per SMEM chunk (1024 words)
+ENTRY_WORDS = 3          # (dst row, src row, weight)
+ENTRY_CHUNK = 2048       # entries per SMEM chunk (6144 words, 24 KiB)
+HBM_ALIGN = 1024         # words
+ROW_TILE = 64            # rows per elementwise tile; n_pad is a multiple
+LANES = 128
+# Scoped VMEM the kernel may ask for: the TPU v5e core has 128 MiB.
+VMEM_CAP_BYTES = 128 * 1024 * 1024
+_VMEM_SLACK_BYTES = 4 * 1024 * 1024
 
 
 def control_digest(steps, consts, plan_parts=()) -> str:
@@ -87,145 +86,228 @@ def control_digest(steps, consts, plan_parts=()) -> str:
         ("|".join(OPCODES), steps, consts) + tuple(plan_parts))
 
 
+def vmem_bytes(n_pad: int, lane_block: int, n_regs: int,
+               itemsize: int) -> int:
+    """VMEM one launch asks for: the register file plus the PERMUTE
+    accumulator at ``lane_block`` lanes, and one constants block."""
+    return ((n_regs + 1) * n_pad * lane_block * itemsize
+            + n_pad * LANES * 4 + _VMEM_SLACK_BYTES)
+
+
+def lane_block(n_pad: int, d_pad: int, n_regs: int, itemsize: int) -> int:
+    """The widest lane block (a multiple of 128 dividing ``d_pad``, at
+    most 1024) whose register file fits the VMEM cap.  Raises when not
+    even 128 lanes fit: the state is too tall for one core's VMEM."""
+    for q in (8, 4, 2, 1):
+        blk = LANES * q
+        if (d_pad % blk == 0 and vmem_bytes(n_pad, blk, n_regs, itemsize)
+                <= VMEM_CAP_BYTES):
+            return blk
+    raise ValueError(
+        f"plan program state of {n_pad} rows x {n_regs} registers needs "
+        f"{vmem_bytes(n_pad, LANES, n_regs, itemsize)} bytes of VMEM at "
+        f"{LANES} lanes; the cap is {VMEM_CAP_BYTES}")
+
+
 def _rotlv(v, amt):
     """Per-row rotate-left; amount 0 is the identity (the masked ``&``
     keeps the ``v >> bits`` shift out of UB territory at amt == 0)."""
     bits = jnp.iinfo(v.dtype).bits
-    a = amt.astype(v.dtype)[:, None]
-    return (v << a) | (v >> ((bits - a) & (bits - 1)))
+    return (v << amt) | (v >> ((bits - amt) & (bits - 1)))
 
 
-def _kernel(state_ref, steps_ref, plans_ref, koff_ref, kcnt_ref, folds_ref,
-            w_ref, woff_ref, consts_ref, out_ref, *, n_valid, n_regs,
-            rounds, const_stride, weighted):
-    """The VM: fori_loop(rounds) { scan(steps) { switch(op) } }."""
-    state = state_ref[...]
-    steps = steps_ref[...]          # (n_steps, 6) int32 rows
-    plan_tbl = plans_ref[...]       # (K_total, n_pad) ragged select rows
-    koff = koff_ref[...]            # (n_plans,) first select row
-    kcnt = kcnt_ref[...]            # (n_plans,) select count
-    folds = folds_ref[...]          # (n_plans,) 1 = GF(2) XOR fold
-    w_flat = w_ref[...] if weighted else None   # (KW_total, n_pad)
-    woff = woff_ref[...]            # (n_plans,) weight row or -1
-    consts = consts_ref[...]        # (n_consts, n_pad)
+def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, o_hbm,
+            regs, acc, cblk, step_buf, ent_buf, cur_blk, *,
+            n_steps, n_regs, rounds, const_stride):
+    """The VM over one lane block: rounds { step chunks { steps } }."""
+    n_pad, width = acc.shape
+    dtype = acc.dtype
+    n_tiles = n_pad // ROW_TILE
+    lanes = pl.ds(pl.multiple_of(pl.program_id(0) * width, LANES), width)
 
-    def round_body(rnd, regs):
-        def step_fn(regs, s):
-            op, dst, a, b, p, c = (s[0], s[1], s[2], s[3], s[4], s[5])
-            av = jax.lax.dynamic_index_in_dim(regs, a, 0, keepdims=False)
-            bv = jax.lax.dynamic_index_in_dim(regs, b, 0, keepdims=False)
+    def tiles(body):
+        def run(t, carry):
+            body(pl.ds(pl.multiple_of(t * ROW_TILE, ROW_TILE), ROW_TILE))
+            return carry
+        jax.lax.fori_loop(0, n_tiles, run, 0)
 
-            def const_row():
-                return jax.lax.dynamic_index_in_dim(
-                    consts, c + rnd * const_stride, 0, keepdims=False)
+    pltpu.sync_copy(x_hbm.at[:, lanes], regs.at[0])
+    for r in range(1, n_regs):
+        def zero(rows, r=r):
+            regs[r, rows, :] = jnp.zeros((ROW_TILE, width), dtype)
+        tiles(zero)
+    cur_blk[0] = -1
 
-            def f_permute(_):
-                base = jax.lax.dynamic_index_in_dim(koff, p, 0,
-                                                    keepdims=False)
-                count = jax.lax.dynamic_index_in_dim(kcnt, p, 0,
-                                                     keepdims=False)
-                wbase = jax.lax.dynamic_index_in_dim(woff, p, 0,
-                                                     keepdims=False)
+    def const_column(c):
+        """Load constant ``c``'s block if needed; return a per-tile
+        reader of its (ROW_TILE, 1) column."""
+        blk = c // LANES
 
-                def body(j, accs):
-                    acc_add, acc_xor = accs
-                    src = jax.lax.dynamic_index_in_dim(
-                        plan_tbl, base + j, 0, keepdims=False)
-                    valid = (src >= 0) & (src < n_valid)
-                    g = jnp.take(av, jnp.clip(src, 0, n_valid - 1),
-                                 axis=0)
-                    if weighted:
-                        wrow = jax.lax.dynamic_index_in_dim(
-                            w_flat, jnp.maximum(wbase, 0) + j, 0,
-                            keepdims=False)
-                        wsel = jnp.where(wbase >= 0, wrow,
-                                         jnp.ones_like(wrow))
-                        g = g * wsel[:, None].astype(g.dtype)
-                    g = jnp.where(valid[:, None], g, jnp.zeros_like(g))
-                    # GF(2) accumulates in the carrier: gathered values
-                    # fold to bit 0 (out-of-carrier payloads land where
-                    # apply_plan's ``sum & 1`` puts them), XOR = parity.
-                    gm = g & jnp.ones_like(g)
-                    return (acc_add + g, acc_xor ^ gm)
+        @pl.when(cur_blk[0] != blk)
+        def _():
+            pltpu.sync_copy(consts_hbm.at[blk], cblk)
+            cur_blk[0] = blk
 
-                zero = jnp.zeros_like(av)
-                acc_add, acc_xor = jax.lax.fori_loop(
-                    0, count, body, (zero, zero))
-                is_xor = jax.lax.dynamic_index_in_dim(folds, p, 0,
-                                                      keepdims=False)
-                return jnp.where(is_xor != 0, acc_xor, acc_add)
+        lane = c % LANES
 
-            dispatch = {
-                "permute": f_permute,
-                "xor": lambda _: av ^ bv,
-                "and": lambda _: av & bv,
-                "andn": lambda _: ~av & bv,
-                "add": lambda _: av + bv,
-                "rotlv": lambda _: _rotlv(av, const_row()),
-                "xor_const":
-                    lambda _: av ^ const_row().astype(av.dtype)[:, None],
-                "eq_const":
-                    lambda _: (av == const_row().astype(av.dtype)[:, None]
-                               ).astype(av.dtype),
-            }
-            val = jax.lax.switch(op, [dispatch[o] for o in OPCODES], None)
-            regs = jax.lax.dynamic_update_index_in_dim(regs, val, dst, 0)
-            return regs, None
+        def column(rows):
+            tile = cblk[rows, :]
+            hit = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1) == lane
+            col = jnp.sum(jnp.where(hit, tile, 0), axis=1, keepdims=True)
+            return col.astype(dtype)
+        return column
 
-        regs, _ = jax.lax.scan(step_fn, regs, steps)
-        return regs
+    def permute(dst, a, p):
+        off = meta_ref[4 * p]
+        count = meta_ref[4 * p + 1]
+        is_xor = meta_ref[4 * p + 2]
 
-    regs = jnp.concatenate(
-        [state[None], jnp.zeros((n_regs - 1,) + state.shape, state.dtype)],
-        axis=0)
-    if rounds == 1:
-        regs = round_body(0, regs)
-    else:
-        regs = jax.lax.fori_loop(0, rounds, round_body, regs)
-    out_ref[...] = regs[0]
+        def zero(rows):
+            acc[rows, :] = jnp.zeros((ROW_TILE, width), dtype)
+        tiles(zero)
+
+        def fold(xor):
+            def body(e, carry):
+                d = ent_buf[ENTRY_WORDS * e]
+                s = ent_buf[ENTRY_WORDS * e + 1]
+                w = ent_buf[ENTRY_WORDS * e + 2].astype(dtype)
+                g = regs[a, pl.ds(s, 1), :] * w
+                cur = acc[pl.ds(d, 1), :]
+                acc[pl.ds(d, 1), :] = (cur ^ (g & 1)) if xor else (cur + g)
+                return carry
+            return body
+
+        def chunk(ci, carry):
+            start = pl.multiple_of(off + ci * (ENTRY_WORDS * ENTRY_CHUNK),
+                                   HBM_ALIGN)
+            pltpu.sync_copy(
+                ent_hbm.at[pl.ds(start, ENTRY_WORDS * ENTRY_CHUNK)], ent_buf)
+            m = jnp.minimum(ENTRY_CHUNK, count - ci * ENTRY_CHUNK)
+
+            @pl.when(is_xor != 0)
+            def _():
+                jax.lax.fori_loop(0, m, fold(True), 0)
+
+            @pl.when(is_xor == 0)
+            def _():
+                jax.lax.fori_loop(0, m, fold(False), 0)
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(count, ENTRY_CHUNK), chunk, 0)
+
+        def store(rows):
+            regs[dst, rows, :] = acc[rows, :]
+        tiles(store)
+
+    def elementwise(dst, a, b, fn):
+        def body(rows):
+            regs[dst, rows, :] = fn(regs[a, rows, :], regs[b, rows, :])
+        tiles(body)
+
+    def with_const(dst, a, c, fn):
+        column = const_column(c)
+
+        def body(rows):
+            regs[dst, rows, :] = fn(regs[a, rows, :], column(rows))
+        tiles(body)
+
+    binary = {"xor": lambda u, v: u ^ v,
+              "and": lambda u, v: u & v,
+              "andn": lambda u, v: ~u & v,
+              "add": lambda u, v: u + v}
+    with_c = {"rotlv": _rotlv,
+              "xor_const": lambda u, col: u ^ col,
+              "eq_const": lambda u, col: jnp.where(
+                  u == col, jnp.ones_like(u), jnp.zeros_like(u))}
+
+    def step(rnd, base):
+        op = step_buf[base]
+        dst = step_buf[base + 1]
+        a = step_buf[base + 2]
+        b = step_buf[base + 3]
+        p = step_buf[base + 4]
+        c = step_buf[base + 5] + rnd * const_stride
+        for code, name in enumerate(OPCODES):
+            @pl.when(op == code)
+            def _(name=name):
+                if name == "permute":
+                    permute(dst, a, p)
+                elif name in binary:
+                    elementwise(dst, a, b, binary[name])
+                else:
+                    with_const(dst, a, c, with_c[name])
+
+    def round_body(rnd, carry):
+        def chunk(ci, carry):
+            start = pl.multiple_of(ci * (STEP_WORDS * STEP_CHUNK), HBM_ALIGN)
+            pltpu.sync_copy(steps_hbm.at[pl.ds(start, STEP_WORDS * STEP_CHUNK)],
+                            step_buf)
+            m = jnp.minimum(STEP_CHUNK, n_steps - ci * STEP_CHUNK)
+
+            def body(si, carry):
+                step(rnd, si * STEP_WORDS)
+                return carry
+            jax.lax.fori_loop(0, m, body, 0)
+            return carry
+        jax.lax.fori_loop(0, pl.cdiv(n_steps, STEP_CHUNK), chunk, 0)
+        return carry
+
+    jax.lax.fori_loop(0, rounds, round_body, 0)
+    pltpu.sync_copy(regs.at[0], o_hbm.at[:, lanes])
 
 
 def plan_program_pallas(
     state: jax.Array,
     steps: jax.Array,
-    plan_tbl: jax.Array,
-    koff: jax.Array,
-    kcnt: jax.Array,
-    folds: jax.Array,
-    w_flat: jax.Array | None,
-    woff: jax.Array,
+    entries: jax.Array,
+    meta: jax.Array,
     consts: jax.Array,
     *,
-    n_valid: int,
+    n_steps: int,
     n_regs: int,
     rounds: int = 1,
     const_stride: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
-    """Raw megakernel entry; operands must already be row/lane padded.
+    """Raw megakernel entry; operands come from ``encode_*`` in
+    ``core.plan_program``.
 
-    state: (n_pad, d_pad); steps: (n_steps, 6) int32 rows of
-    (opcode, dst, a, b, plan, const) — one round's stream; plan_tbl:
-    (K_total, n_pad) int32 — every plan's select columns concatenated,
-    one row per column (pad rows DROP); koff/kcnt: (n_plans,) int32
-    per-plan first-row offset / column count into plan_tbl; folds:
-    (n_plans,) int32, 1 for GF(2) XOR accumulation; w_flat: the ragged
-    weight rows for weighted plans (or None when no plan is weighted);
-    woff: (n_plans,) int32 first weight row per plan, -1 = unweighted;
-    consts: (n_consts, n_pad) int32 (a 1-row zero table when unused).
-    Returns (n_pad, d_pad) in state.dtype.
+    state: (n_pad, d_pad), n_pad a multiple of ROW_TILE and d_pad of
+    128; steps: flat int32, STEP_WORDS per step, padded to whole
+    STEP_CHUNKs; entries: flat int32 (dst, src, weight) triples, each
+    plan's run starting at a multiple of HBM_ALIGN words, with one
+    ENTRY_CHUNK of tail padding; meta: (4 * n_plans,) int32 per-plan
+    (entry offset, entry count, 1 = GF(2) XOR fold, 0); consts:
+    (n_blocks, n_pad, 128) int32, constant ``c`` in lane ``c % 128`` of
+    block ``c // 128``.  Returns (n_pad, d_pad) in state.dtype.
     """
+    n_pad, d_pad = state.shape
+    width = lane_block(n_pad, d_pad, n_regs, state.dtype.itemsize)
     kernel = functools.partial(
-        _kernel, n_valid=n_valid, n_regs=n_regs, rounds=rounds,
-        const_stride=const_stride, weighted=w_flat is not None)
-    # Keep the kernel signature fixed: an unweighted program passes a
-    # (1, 1) placeholder the kernel never reads.
-    operands = [state, steps, plan_tbl, koff, kcnt, folds,
-                (jnp.zeros((1, 1), jnp.int32) if w_flat is None
-                 else w_flat),
-                woff, consts]
+        _kernel, n_steps=n_steps, n_regs=n_regs, rounds=rounds,
+        const_stride=const_stride)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(d_pad // width,),
+        in_specs=[hbm, hbm, hbm, hbm],
+        out_specs=hbm,
+        scratch_shapes=[
+            pltpu.VMEM((n_regs, n_pad, width), state.dtype),
+            pltpu.VMEM((n_pad, width), state.dtype),
+            pltpu.VMEM((n_pad, LANES), jnp.int32),
+            pltpu.SMEM((STEP_WORDS * STEP_CHUNK,), jnp.int32),
+            pltpu.SMEM((ENTRY_WORDS * ENTRY_CHUNK,), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
+        ])
+    need = vmem_bytes(n_pad, width, n_regs, state.dtype.itemsize)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(max(need, 32 * 1024 * 1024),
+                                 VMEM_CAP_BYTES)),
         interpret=interpret,
-    )(*operands)
+    )(meta, steps, entries, consts, state)

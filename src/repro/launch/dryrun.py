@@ -55,6 +55,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import SHAPES, cell_applicable, get_config, list_archs
 from repro.dist import sharding as shd
 from repro.dist.annotate import logical_axes
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.model_zoo import build
 from repro.train import TrainOptions, make_train_step
@@ -450,6 +451,7 @@ def main():
                     help="bf16 live params + f32 master (perf iteration)")
     ap.add_argument("--out", type=str, default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     cells = []
     if args.all:

@@ -12,6 +12,7 @@ import argparse
 import jax
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model_zoo import build
 from repro.serve import ServeOptions, ServingEngine
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

@@ -13,6 +13,7 @@ import jax
 
 from repro.configs import SHAPES, get_config, reduced
 from repro.data import make_pipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model_zoo import build
 from repro.train import TrainOptions, Trainer
 
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--ckpt-dir", type=str, default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     shape = SHAPES[args.shape]

@@ -57,6 +57,23 @@ def _token_batch(cfg, key, batch, seq):
 
 
 def build(cfg: ModelConfig) -> ModelAPI:
+    """The family's ModelAPI, with ``init`` storing floating-point
+    weights in ``cfg.param_dtype``."""
+    api = _build(cfg)
+    dtype = jnp.dtype(cfg.param_dtype)
+    if dtype == jnp.float32:
+        return api
+
+    def init(key):
+        return jax.tree.map(
+            lambda w: (w.astype(dtype)
+                       if jnp.issubdtype(w.dtype, jnp.floating) else w),
+            api.init(key))
+
+    return dataclasses.replace(api, init=init)
+
+
+def _build(cfg: ModelConfig) -> ModelAPI:
     fam = cfg.family
     if fam in ("dense",):
         from repro.models import transformer as M

@@ -370,6 +370,10 @@ def _absorb_digests(blocks: np.ndarray, backend: str, *,
                                      batch_mode="payload",
                                      fixed_latency=fixed_latency,
                                      interpret=interpret)
+    # Lanes per device that produced them: where the shards really ran.
+    on = states.devices()
+    for dev in on:
+        telemetry.incr(f"serve_lanes_device{dev.id}", b // len(on))
     host = np.asarray(states)
     return [keccak._squeeze(host[i], _RATE_BYTES)[:32] for i in range(b)]
 

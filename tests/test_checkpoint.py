@@ -66,10 +66,8 @@ def test_async_manager():
 
 def test_restore_with_new_shardings():
     """Elastic restore: leaves re-placed with provided shardings."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    kw = ({"axis_types": (jax.sharding.AxisType.Auto,)}
-          if hasattr(jax.sharding, "AxisType") else {})  # jax<0.5 compat
-    mesh = jax.make_mesh((1,), ("data",), **kw)
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree())
     with tempfile.TemporaryDirectory() as d:
         save(tree(), d, 1)

@@ -2,7 +2,6 @@
 sharding rules, and a multi-device (8 fake CPU devices) integration run
 in a subprocess."""
 
-import inspect
 import os
 import subprocess
 import sys
@@ -12,22 +11,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.dist import fault
 from repro.dist.collectives import dequantize_int8, quantize_int8
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-def make_auto_mesh(shape, axes):
-    """jax<0.5 has no sharding.AxisType; Auto is the default there anyway."""
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
-
-
-# The subprocess scripts below get the same shim, from the same source.
-_MESH_COMPAT = textwrap.dedent(inspect.getsource(make_auto_mesh))
 
 
 class TestElasticPolicy:
@@ -133,7 +122,8 @@ class TestShardingRules:
     def test_param_rules_divisibility_fallback(self):
         from jax.sharding import PartitionSpec as P
         from repro.dist import sharding as shd
-        mesh = make_auto_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         params = {"blocks": {"attn": {"wq": {"w": jnp.zeros((7, 13))}}}}
         sh = shd.param_shardings(params, mesh, None)
         # sizes 7/13 divide 1, so specs apply
@@ -141,18 +131,19 @@ class TestShardingRules:
 
     def test_cache_rules(self):
         from repro.dist import sharding as shd
-        mesh = make_auto_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         caches = {"k": jnp.zeros((2, 4, 8, 2, 16))}
         sh = shd.cache_shardings(caches, mesh, None)
         assert sh["k"].spec is not None
 
 
-MULTIDEV_SCRIPT = _MESH_COMPAT + textwrap.dedent("""
+MULTIDEV_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs.base import ModelConfig
     from repro.models.model_zoo import build
     from repro.train import TrainOptions, make_train_step
@@ -166,7 +157,8 @@ MULTIDEV_SCRIPT = _MESH_COMPAT + textwrap.dedent("""
                       head_dim=8, compute_dtype="float32", remat="none",
                       attn_chunk=8)
     api = build(cfg)
-    mesh = make_auto_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     pipe = SyntheticLM(vocab_size=128, seq_len=16, global_batch=8)
     params = api.init(jax.random.PRNGKey(0))
     state = init_state(params, jax.random.PRNGKey(0))
@@ -213,20 +205,19 @@ def test_sharded_train_step_matches_single_device():
     assert "MULTIDEV-OK" in proc.stdout, proc.stderr[-2000:]
 
 
-COMPRESSED_SCRIPT = _MESH_COMPAT + textwrap.dedent("""
+COMPRESSED_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     import numpy as np
     from functools import partial
-    from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import AxisType, Mesh, PartitionSpec as P
     from repro.dist.collectives import compressed_psum
 
-    mesh = make_auto_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
-    @partial(shard_map, mesh=mesh, in_specs=P("data", None),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("data", None),
              out_specs=(P("data", None), P("data", None)))
     def reduce_compressed(gs):
         mean, err = compressed_psum(gs[0], "data")
@@ -248,17 +239,17 @@ def test_compressed_psum_shardmap():
     assert "COMPRESSED-OK" in proc.stdout, proc.stderr[-2000:]
 
 
-KECCAK_SHARDED_SCRIPT = _MESH_COMPAT + textwrap.dedent("""
+KECCAK_SHARDED_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import hashlib, time
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.crypto import keccak as kk
     from repro.dist.annotate import logical_axes
 
-    mesh = make_auto_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 
     # End-to-end: B=8 sponge lanes sharded one per device via the
     # "batch" annotation in sha3_256_batched; digests must stay exact.

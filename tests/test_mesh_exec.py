@@ -4,7 +4,6 @@ shard-restricted plans, the tuning table, per-device health, and the
 collective-free HLO for lane-parallel programs, survivor-mesh serving)
 run in subprocesses so XLA_FLAGS takes effect before jax import."""
 
-import inspect
 import os
 import subprocess
 import sys
@@ -14,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core import crossbar as xb
 from repro.core import plan_algebra as pa
@@ -26,16 +26,6 @@ from repro.dist import sharding as shd
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-def make_auto_mesh(shape, axes):
-    """jax<0.5 has no sharding.AxisType; Auto is the default there anyway."""
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
-
-
-_MESH_COMPAT = textwrap.dedent(inspect.getsource(make_auto_mesh))
 
 
 def _run_sub(script, sentinel, timeout=600):
@@ -136,14 +126,14 @@ class TestShardRestrict:
 
 class TestInputValidation:
     def test_mesh_axis_size_unknown_axis(self):
-        mesh = make_auto_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         with pytest.raises(ValueError, match="not on the mesh"):
             shd.mesh_axis_size(mesh, ("model",))
 
     def test_require_divisible(self):
         # a 1-device mesh divides everything; the indivisible branch is
         # exercised on 8 devices in SHARDED_PROGRAM_SCRIPT below
-        mesh = make_auto_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         assert shd.require_divisible(8, mesh, ("data",)) == 8
         with pytest.raises(ValueError, match="not on the mesh"):
             shd.require_divisible(7, mesh, ("bogus",))
@@ -159,7 +149,7 @@ class TestInputValidation:
             compressed_psum(jnp.ones((4,)), "nonexistent_axis")
 
     def test_sharded_apply_unknown_axis(self):
-        mesh = make_auto_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         idx = jnp.arange(8, dtype=jnp.int32)[:, None]
         plan = xb.gather_plan(idx, 8, semiring=GF2)
         with pytest.raises(ValueError, match="not on mesh"):
@@ -272,16 +262,17 @@ class TestDeviceHealth:
 # 8-fake-device differential suites (subprocess: XLA_FLAGS before import).
 # ---------------------------------------------------------------------------
 
-SHARDED_APPLY_SCRIPT = _MESH_COMPAT + textwrap.dedent("""
+SHARDED_APPLY_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
+    from jax.sharding import AxisType
     import numpy as np
     from repro.core import crossbar as xb
     from repro.core.semiring import GF2, REAL
     from repro.dist import mesh_exec as mx
 
-    mesh = make_auto_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(0)
     n = 1600
 
@@ -344,16 +335,17 @@ def test_sharded_apply_matches_single_device():
     _run_sub(SHARDED_APPLY_SCRIPT, "SHARDED-APPLY-OK")
 
 
-SHARDED_PROGRAM_SCRIPT = _MESH_COMPAT + textwrap.dedent("""
+SHARDED_PROGRAM_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
+    from jax.sharding import AxisType
     import numpy as np
     from repro.core import plan_program as pp
     from repro.crypto import keccak as kk
     from repro.dist import mesh_exec as mx
 
-    mesh = make_auto_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     prog = kk.megakernel_program()
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.integers(0, 2, (1600, 16)), jnp.int32)
@@ -388,7 +380,7 @@ def test_sharded_program_collective_free():
     _run_sub(SHARDED_PROGRAM_SCRIPT, "SHARDED-PROGRAM-OK")
 
 
-SURVIVOR_SCRIPT = _MESH_COMPAT + textwrap.dedent("""
+SURVIVOR_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import hashlib
@@ -435,7 +427,7 @@ def test_survivor_mesh_keeps_answering():
     _run_sub(SURVIVOR_SCRIPT, "SURVIVOR-OK")
 
 
-PARTIAL_REPLAY_SCRIPT = _MESH_COMPAT + textwrap.dedent("""
+PARTIAL_REPLAY_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import hashlib

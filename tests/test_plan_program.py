@@ -25,6 +25,7 @@ from repro.core import telemetry
 from repro.core.semiring import GF2, GF2_8
 from repro.core.static_registry import FixedLatencyError, StaticPlanRegistry
 from repro.crypto import chacha as cc
+from repro.crypto import gcm
 from repro.crypto import keccak as kk
 from repro.crypto.registry import REGISTRY
 
@@ -114,6 +115,19 @@ class TestProgramIR:
         assert cc.megakernel_program().passes == 10 * 18
 
 
+def _many_consts_program(n=16):
+    """More constant rows than one 128-lane constants block holds, so
+    the megakernel switches constant blocks mid-program."""
+    rng = np.random.default_rng(11)
+    b = pp.ProgramBuilder("many_consts", n, n_regs=2)
+    for _ in range(130):
+        b.xor_const(0, 0, rng.integers(0, 2, n))
+    b.eq_const(1, 0, rng.integers(0, 2, n))
+    b.xor_const(0, 0, rng.integers(0, 2, n))
+    b.add(0, 0, 1)
+    return b.build()
+
+
 class TestDifferential:
     def test_synthetic_program_all_ops(self):
         prog = _synthetic_program()
@@ -156,6 +170,22 @@ class TestDifferential:
         np.testing.assert_array_equal(
             np.asarray(pp.run_program(full, x, backend="chained")),
             np.asarray(pp.run_program(full, x, backend="megakernel")))
+
+    @pytest.mark.parametrize("name", ["keccak", "gcm_seal", "many_consts"])
+    def test_served_program_over_lane_blocks(self, name):
+        """Megakernel == chained on whole served programs at 300 lanes:
+        three 128-lane grid steps of the megakernel."""
+        if name == "keccak":
+            prog = kk.megakernel_program()
+        elif name == "gcm_seal":
+            _, prog, _ = gcm.gcm_program(bytes(range(16)), 16, 16)
+        else:
+            prog = _many_consts_program()
+        x = _bits(6, (prog.n, 300))
+        np.testing.assert_array_equal(
+            np.asarray(pp.run_program(prog, x, backend="chained",
+                                      pass_backend="reference")),
+            np.asarray(pp.run_program(prog, x, backend="megakernel")))
 
     def test_weighted_real_program(self):
         rng = np.random.default_rng(5)

@@ -126,6 +126,47 @@ class TestCompiledPlan:
         assert num == xb.compile_plan(xb.gather_plan(idx, n)).num_active
 
 
+class TestCacheability:
+    """``xb.is_cacheable``: a cache may be keyed only outside every trace
+    and only on concrete operands — the verdicts the trace-state checks
+    it replaced gave under jit, vmap and compile-time evaluation."""
+
+    def test_concrete_outside_any_trace(self):
+        x = jnp.arange(4)
+        assert xb.is_cacheable()
+        assert xb.is_cacheable(x, None)
+
+    @pytest.mark.parametrize("transform", ["jit", "vmap"])
+    def test_live_trace_is_not_cacheable(self, transform):
+        x = jnp.arange(4)
+        seen = {}
+
+        def probe(y):
+            seen["bare"] = xb.is_cacheable()
+            seen["concrete"] = xb.is_cacheable(x)
+            seen["traced"] = xb.is_cacheable(y)
+            return y
+
+        wrap = jax.jit if transform == "jit" else jax.vmap
+        wrap(probe)(jnp.ones(4))
+        assert seen == {"bare": False, "concrete": False, "traced": False}
+
+    def test_compile_time_eval_is_cacheable(self):
+        x = jnp.arange(4)
+        with jax.ensure_compile_time_eval():
+            assert xb.is_cacheable(x)
+
+    def test_in_trace_schedule_is_not_stored(self):
+        xb.clear_compile_cache()
+        n = 256
+        idx = jax.random.randint(KEY, (n, 1), 0, n, dtype=jnp.int32)
+        plan = xb.gather_plan(idx, n)
+        jax.jit(lambda: xb.compile_plan(plan).occupancy)()
+        assert xb.compile_cache_info()["size"] == 0
+        assert xb.compile_plan(plan).is_static
+        assert xb.compile_cache_info()["size"] == 1
+
+
 class TestSparseDifferential:
     @pytest.mark.parametrize("mode", ["gather", "scatter"])
     @pytest.mark.parametrize("weighted", [False, True])

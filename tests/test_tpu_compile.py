@@ -1,0 +1,89 @@
+"""Ahead-of-time compiles of the main-path kernels for a TPU v5e.
+
+Nothing runs here: each test lowers a kernel at its served size and
+compiles it for a described (not attached) v5e chip, so a kernel that
+Mosaic or the TPU compiler would refuse — an unaligned slice, more VMEM
+or SMEM than the chip has, an unsupported primitive — fails on a CPU
+host.  The topology is described inside a module-scoped fixture: only
+the process that runs these tests loads the TPU compiler library.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import plan_program as pp
+from repro.crypto import gcm, keccak
+from repro.kernels import crossbar_permute as cp
+from repro.kernels import plan_program_kernel as ppk
+
+N, D = 4096, 512          # crossbar kernels: the chip smoke's served size
+GCM_KEY = bytes(range(16))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache off meanwhile.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    return compiled
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_dense_crossbar_compiles(one_chip):
+    fn = functools.partial(cp.crossbar_permute_pallas, mode="gather",
+                           n_out=N, n_in_valid=N)
+    _compile(fn, _spec((N, 1), jnp.int32, one_chip),
+             _spec((N, D), jnp.float32, one_chip))
+
+
+def test_sparse_crossbar_compiles(one_chip):
+    pairs = N // cp.DEFAULT_BO
+    fn = functools.partial(cp.crossbar_permute_sparse_pallas, mode="gather",
+                           n_out=N)
+    _compile(fn, *(_spec((pairs,), jnp.int32, one_chip),) * 3,
+             _spec((N, 1), jnp.int32, one_chip),
+             _spec((N, D), jnp.float32, one_chip))
+
+
+def _compile_program(program, lanes, sharding):
+    n_pad = program.n + (-program.n) % ppk.ROW_TILE
+    control, static = pp.encode_program(program, n_pad)
+    fn = functools.partial(ppk.plan_program_pallas, **static)
+    return _compile(fn, _spec((n_pad, lanes), jnp.int32, sharding),
+                    *(_spec(c.shape, c.dtype, sharding) for c in control))
+
+
+def test_megakernel_keccak_compiles(one_chip):
+    _compile_program(keccak.megakernel_program(), 128, one_chip)
+
+
+def test_megakernel_gcm_seal_compiles(one_chip):
+    _, program, _ = gcm.gcm_program(GCM_KEY, 1024, 16)
+    _compile_program(program, 128, one_chip)
