@@ -68,7 +68,6 @@ GCM-SIV follow-up.
 from __future__ import annotations
 
 import hmac
-import time
 from collections import OrderedDict
 from typing import List, Optional, Sequence, Tuple
 
@@ -671,13 +670,6 @@ def _unpack_records(out: np.ndarray, m: int, pt_len: int
     return bodies, tags
 
 
-def _size_bucket(nbytes: int) -> int:
-    b = 16
-    while b < nbytes:
-        b *= 2
-    return b
-
-
 # ---------------------------------------------------------------------------
 # Fused batch seal/open
 # ---------------------------------------------------------------------------
@@ -704,18 +696,29 @@ def _check_batch(ivs, records, aads):
     return aads
 
 
+def no_phase(name: Optional[str] = None) -> None:
+    """The default ``phase`` hook of the batch seal: times nothing.
+
+    A caller that times the stages of a seal passes a callable told the
+    name of each stage as it begins (``launch``: the transfer and the
+    program launch; ``sync``: the wait for the device's result;
+    ``unpack``: the output bits back to bytes), which ends the stage
+    before it; ``serve.batching`` makes its bucket spans from them.
+    """
+
+
 def _run_fused(key: bytes, ivs, records, aads, pt_len: int, aad_len: int,
                *, open_mode: bool, fixed_latency: bool,
-               interpret: Optional[bool]):
+               interpret: Optional[bool], phase=no_phase):
     m, _, a = _geometry(pt_len, aad_len)
     prog_key, program, lay = gcm_program(key, pt_len, aad_len,
                                          open_mode=open_mode)
-    bts = jnp.asarray(_pack_records(lay, ivs, records, aads, pt_len,
-                                    aad_len))
+    bits = _pack_records(lay, ivs, records, aads, pt_len, aad_len)
+    phase("launch")
+    bts = jnp.asarray(bits)
     op = "gcm_open" if open_mode else "gcm_seal"
     launches0 = pp.program_launch_count()
     passes0 = pp.passes_avoided_count()
-    t0 = time.perf_counter()
 
     def run():
         with _obs.span(op, records=len(ivs), blocks=m, aad_blocks=a,
@@ -732,17 +735,15 @@ def _run_fused(key: bytes, ivs, records, aads, pt_len: int, aad_len: int,
             out = run()
     else:
         out = run()
+    phase("sync")
     out_np = np.asarray(out)
-    elapsed = time.perf_counter() - t0
     telemetry.incr(f"{op}_calls")
     telemetry.incr(f"{op}_records", len(ivs))
     telemetry.incr(f"{op}_launches",
                    pp.program_launch_count() - launches0)
     telemetry.incr("gcm_passes_avoided",
                    pp.passes_avoided_count() - passes0)
-    if not open_mode:
-        _obs.metrics.histogram(
-            f"gcm_seal_latency_rec{_size_bucket(pt_len)}b").observe(elapsed)
+    phase("unpack")
     return _unpack_records(out_np, m, pt_len)
 
 
@@ -782,12 +783,15 @@ def aes128_gcm_seal_batch(key: bytes, ivs: Sequence[bytes],
                           aads: Optional[Sequence[bytes]] = None, *,
                           backend: str = "fused",
                           fixed_latency: bool = False,
-                          interpret: Optional[bool] = None) -> List[bytes]:
+                          interpret: Optional[bool] = None,
+                          phase=no_phase) -> List[bytes]:
     """Seal B same-geometry records; returns ``ciphertext || tag`` each.
 
     backend='fused' runs the whole batch as ONE plan-program launch;
     any crossbar backend name runs the chained per-block lowering
-    per record (the CAVP reference path).
+    per record (the CAVP reference path), which launches and syncs per
+    block and so reads as one ``launch`` stage to ``phase``
+    (``no_phase`` says what it is told).
     """
     aads = _check_batch(ivs, plaintexts, aads)
     pt_len, aad_len = len(plaintexts[0]), len(aads[0])
@@ -795,8 +799,9 @@ def aes128_gcm_seal_batch(key: bytes, ivs: Sequence[bytes],
         bodies, tags = _run_fused(key, ivs, plaintexts, aads, pt_len,
                                   aad_len, open_mode=False,
                                   fixed_latency=fixed_latency,
-                                  interpret=interpret)
+                                  interpret=interpret, phase=phase)
         return [c + t for c, t in zip(bodies, tags)]
+    phase("launch")
     out = []
     for iv, pt, aad in zip(ivs, plaintexts, aads):
         c, t = _seal_chained_core(key, iv, pt, aad, open_mode=False,

@@ -310,4 +310,5 @@ def plan_program_pallas(
             vmem_limit_bytes=min(max(need, 32 * 1024 * 1024),
                                  VMEM_CAP_BYTES)),
         interpret=interpret,
+        name="plan_program",
     )(meta, steps, entries, consts, state)

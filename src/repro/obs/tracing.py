@@ -25,6 +25,27 @@ Design constraints, in order:
   engine (``core.crossbar``) and must not import anything from
   ``repro`` — metrics feeding happens via a registered sink callback
   (``repro.obs.metrics`` installs itself on import).
+* **One clock with the device profile.**  A recording span named in
+  ``PROFILER_SPANS`` also enters ``jax.profiler.TraceAnnotation(name)``
+  (the name alone, no attributes), so the host phases that leave the
+  device idle show up in a ``jax.profiler`` trace on ``/host:CPU``,
+  in the recording thread's line, beside the device's operations.
+  Only leaf phases are mirrored: an enclosing span (``bucket_feed``,
+  ``device_absorb``, ``resilient_execute``, ``registry_observe``,
+  ``gcm_seal``, ``request``) would overlap every gap between device
+  operations and hide the phase that fills it.  Spans recorded after
+  the fact with ``span_at`` (``queue_wait``, ``bucket_wait``,
+  ``request``) are waits between two threads, not work on one, and
+  never reach the profiler.  ``jax.profiler`` is looked up in
+  ``sys.modules`` when such a span opens, never imported here: a
+  process that has not imported JAX has no device to line up with.
+* **Garbage collection is a phase too.**  While recording is on, a
+  ``gc.callbacks`` hook times each collection of the oldest generation
+  as a mirrored ``gc_pause`` span on the collecting thread (every other
+  thread is held up meanwhile).  The hook records nothing itself — a
+  collection can start inside ``_record`` with its lock held — and
+  leaves the finished span for the next ``_record`` or
+  ``finished_spans()`` to file.  ``disable()`` removes the hook.
 
 The buffer is exported two ways: ``finished_spans()`` (raw records,
 consumed by the metrics histograms and tests) and
@@ -34,8 +55,10 @@ consumed by the metrics histograms and tests) and
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import os
+import sys
 import threading
 import time
 from typing import Callable, Optional
@@ -70,6 +93,58 @@ def _truthy_env(name: str, default: str = "0") -> bool:
         "", "0", "false", "no", "off")
 
 
+# Spans that enter a jax.profiler.TraceAnnotation of their name while
+# they record: the leaf host phases of serving a bucket, the megakernel
+# launch, and garbage collection.
+PROFILER_SPANS = frozenset({
+    "feed_wait", "bucket_pack", "bucket_launch", "bucket_sync",
+    "bucket_unpack", "program_launch", "gc_pause"})
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation(name)``, or None where JAX's
+    profiler has not been imported."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+# The collection of the oldest generation in progress ([t0, annotation])
+# and the finished gc_pause spans waiting to be filed.
+_GC_OPEN: list = []
+_GC_DONE: "collections.deque" = collections.deque()
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _GC_OPEN[:] = [time.perf_counter(), _annotation("gc_pause")]
+        return
+    if not _GC_OPEN:
+        return
+    t1 = time.perf_counter()
+    t0, ann = _GC_OPEN
+    _GC_OPEN.clear()
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    sp = Span("gc_pause", {"collected": info["collected"]})
+    sp.trace_id = next(_IDS)
+    sp.t0, sp.t1 = t0, t1
+    _GC_DONE.append(sp)
+
+
+def _set_gc_hook(on: bool) -> None:
+    if on and _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
+    elif not on and _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
+        _GC_OPEN.clear()
+
+
 _ENABLED = _truthy_env("REPRO_OBS")
 
 
@@ -81,11 +156,13 @@ def enabled() -> bool:
 def enable() -> None:
     global _ENABLED
     _ENABLED = True
+    _set_gc_hook(True)
 
 
 def disable() -> None:
     global _ENABLED
     _ENABLED = False
+    _set_gc_hook(False)
 
 
 def new_trace_id() -> int:
@@ -145,7 +222,8 @@ class Span:
     """
 
     __slots__ = ("name", "attrs", "trace_id", "span_id", "parent_id",
-                 "thread_id", "thread_name", "t0", "t1", "events")
+                 "thread_id", "thread_name", "t0", "t1", "events",
+                 "annotation")
 
     recording = True
 
@@ -162,6 +240,7 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.events: "list[tuple]" = []
+        self.annotation = None
 
     def __enter__(self) -> "Span":
         stack = getattr(_TLS, "stack", None)
@@ -174,11 +253,15 @@ class Span:
         if self.trace_id is None:
             self.trace_id = getattr(_TLS, "trace_id", None) or next(_IDS)
         stack.append(self)
+        if self.name in PROFILER_SPANS:
+            self.annotation = _annotation(self.name)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.t1 = time.perf_counter()
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
         stack = getattr(_TLS, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
@@ -192,9 +275,12 @@ class Span:
     def duration_s(self) -> float:
         return self.t1 - self.t0
 
-    def set(self, **attrs) -> "Span":
+    def set(self, *, trace_id: Optional[int] = None, **attrs) -> "Span":
         """Attach attributes discovered mid-span (resolved backend,
-        batch size after padding, ...)."""
+        batch size after padding, ...); ``trace_id`` adopts a trace
+        learnt mid-span (a wait that finds out what it waited for)."""
+        if trace_id is not None:
+            self.trace_id = trace_id
         self.attrs.update(attrs)
         return self
 
@@ -251,6 +337,20 @@ def event(name: str, *, trace_id: Optional[int] = None, **attrs) -> None:
 
 
 def _record(sp: Span) -> None:
+    _file(sp)
+    _file_gc_pauses()
+
+
+def _file_gc_pauses() -> None:
+    while _GC_DONE:
+        try:
+            sp = _GC_DONE.popleft()
+        except IndexError:  # another thread filed the last one
+            return
+        _file(sp)
+
+
+def _file(sp: Span) -> None:
     global _DROPPED
     with _LOCK:
         if len(_SPANS) == _SPANS.maxlen:
@@ -273,6 +373,7 @@ def add_sink(fn: Callable) -> None:
 
 def finished_spans() -> list:
     """A consistent copy of the ring buffer (oldest first)."""
+    _file_gc_pauses()
     with _LOCK:
         return list(_SPANS)
 
@@ -295,6 +396,7 @@ def clear() -> None:
     with _LOCK:
         _SPANS.clear()
         _DROPPED = 0
+    _GC_DONE.clear()
     _DISABLED_CALLS = 0
 
 
@@ -305,3 +407,6 @@ def set_buffer_capacity(cap: int) -> None:
         raise ValueError(f"span buffer capacity must be >= 1, got {cap}")
     with _LOCK:
         _SPANS = collections.deque(_SPANS, maxlen=cap)
+
+
+_set_gc_hook(_ENABLED)

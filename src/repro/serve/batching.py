@@ -338,9 +338,12 @@ def _absorb_digests(blocks: np.ndarray, backend: str, *,
                     fixed_latency: bool,
                     interpret: Optional[bool] = None,
                     mesh=None, mesh_axis: str = "data",
-                    device=None) -> list:
+                    device=None, phase=gcm.no_phase) -> list:
     """Device-side half: sponge-absorb pre-packed blocks, one
-    ``keccak_f1600`` per block, and squeeze the digests.
+    ``keccak_f1600`` per block, and squeeze the digests.  ``phase`` is
+    told as each stage begins: ``launch`` (the transfers and dispatches
+    of every block), ``sync`` (the wait for the states), ``unpack`` (the
+    squeeze).
 
     With ``mesh`` set, the batch axis is sharded over ``mesh_axis`` —
     every absorb step (XOR + keccak_f1600 with B as payload width) is
@@ -351,6 +354,7 @@ def _absorb_digests(blocks: np.ndarray, backend: str, *,
     recovery path executes each shard's lane window as its own
     journaled unit this way.
     """
+    phase("launch")
     b, n_blocks = blocks.shape[:2]
     states = jnp.zeros((b, keccak.STATE_BITS), jnp.int32)
     shard = mesh is not None and backend != "megakernel" and b > 1
@@ -374,7 +378,9 @@ def _absorb_digests(blocks: np.ndarray, backend: str, *,
     on = states.devices()
     for dev in on:
         telemetry.incr(f"serve_lanes_device{dev.id}", b // len(on))
+    phase("sync")
     host = np.asarray(states)
+    phase("unpack")
     return [keccak._squeeze(host[i], _RATE_BYTES)[:32] for i in range(b)]
 
 
@@ -406,17 +412,21 @@ def _keccak_registry_keys(backend: str) -> tuple:
 
 def _bucket_seal(payloads: Sequence[bytes], backend: str, key: bytes, *,
                  fixed_latency: bool,
-                 interpret: Optional[bool] = None) -> list:
+                 interpret: Optional[bool] = None,
+                 phase=gcm.no_phase) -> list:
     """Seal one AEAD bucket: decode the wire records and run the whole
     batch as ONE fused GCM program launch (backend='megakernel'), or the
-    chained per-block lowering on a crossbar backend when degraded."""
+    chained per-block lowering on a crossbar backend when degraded.
+    ``phase("pack")`` opens the decode, which the seal's own bit
+    packing continues; the seal tells ``phase`` the stages after it."""
+    phase("pack")
     recs = [_decode_aead_record(p) for p in payloads]
     be = "fused" if backend == "megakernel" else backend
     return gcm.aes128_gcm_seal_batch(
         key, [r[0] for r in recs], [r[1] for r in recs],
         [r[2] for r in recs], backend=be,
         fixed_latency=fixed_latency and be == "fused",
-        interpret=interpret)
+        interpret=interpret, phase=phase)
 
 
 def _gcm_registry_keys(key: bytes, pt_len: int, aad_len: int):
@@ -427,6 +437,30 @@ def _gcm_registry_keys(key: bytes, pt_len: int, aad_len: int):
             return (gcm._program_key(key, pt_len, aad_len, False),)
         return (gcm._ghash_plan_key(gcm._hash_key(key), "horner", 1),)
     return keys
+
+
+class _Phases:
+    """The host phases of one bucket execution on the feed thread, as
+    one sequence of spans: ``phase("launch")`` ends the open phase and
+    opens ``bucket_launch``; ``phase()`` ends the last one.  A phase is
+    ended by the next one starting rather than by a ``with`` block
+    because the stages of a GCM seal live in two modules (the record
+    decode here, the bit packing in ``crypto.gcm``) and make one phase.
+    """
+
+    __slots__ = ("_attrs", "_open")
+
+    def __init__(self, **attrs):
+        self._attrs = attrs
+        self._open = None
+
+    def __call__(self, name: Optional[str] = None) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        if name is not None:
+            self._open = _obs.span("bucket_" + name, **self._attrs)
+            self._open.__enter__()
 
 
 class BatchingEngine:
@@ -705,10 +739,8 @@ class BatchingEngine:
                        n_blocks=n_blocks, lanes=len(batch), b_pad=b_pad):
             return op, geom, b_pad, _pack_blocks(payloads)
 
-    def _execute_batch(self, batch: list,
-                       prepared: Optional[tuple] = None) -> None:
-        op, geom, b_pad, data = (prepared if prepared is not None
-                                 else self._prepare(batch))
+    def _execute_batch(self, batch: list, prepared: tuple) -> None:
+        op, geom, b_pad, data = prepared
         shape = (b_pad,) + geom
         mesh = self._active_mesh()
         mesh_shape = None if mesh is None else dict(mesh.shape)
@@ -721,20 +753,29 @@ class BatchingEngine:
             return self._execute_batch_partial(batch, op, geom, b_pad,
                                                data, mesh)
 
+        phase = _Phases(trace_id=batch[0].trace_id, op=op,
+                        lanes=len(batch))
         if op == "gcm_seal":
-            def run(backend: str) -> list:
+            def work(backend: str) -> list:
                 return _bucket_seal(data, backend, self.opt.aead_key,
                                     fixed_latency=self.opt.fixed_latency,
-                                    interpret=self.interpret)
+                                    interpret=self.interpret, phase=phase)
             registry_keys = _gcm_registry_keys(self.opt.aead_key, *geom)
         else:
-            def run(backend: str) -> list:
+            def work(backend: str) -> list:
                 return _absorb_digests(data, backend,
                                        fixed_latency=self.opt.fixed_latency,
                                        interpret=self.interpret,
                                        mesh=mesh,
-                                       mesh_axis=self.opt.mesh_axis)
+                                       mesh_axis=self.opt.mesh_axis,
+                                       phase=phase)
             registry_keys = _keccak_registry_keys
+
+        def run(backend: str) -> list:
+            try:
+                return work(backend)
+            finally:
+                phase()
 
         chain = self.tuning.rank_chain(op, shape, self.chain,
                                        mesh_shape=mesh_shape)
@@ -963,8 +1004,29 @@ class BatchingEngine:
         with self._lock:
             batch, rejected = self._take_batch_locked()
         if batch:
-            self._execute_batch(batch)
+            self._feed(*self._stage(batch))
         return len(batch) + rejected
+
+    def _stage(self, batch: list) -> tuple:
+        """``(batch, prepared, t_ready)``: the bucket prepared, and when
+        it became ready to feed (stamped only while spans record)."""
+        prepared = self._prepare(batch)
+        return (batch, prepared,
+                time.perf_counter() if _obs.enabled() else None)
+
+    def _feed(self, batch: list, prepared: tuple,
+              t_ready: Optional[float]) -> None:
+        """The feed thread's whole handling of one bucket, from its
+        pick-up until every request of it is finished.  The time the
+        bucket stood ready before that is its ``bucket_wait``."""
+        head = batch[0]
+        if t_ready is not None:
+            _obs.span_at("bucket_wait", t_ready, time.perf_counter(),
+                         trace_id=head.trace_id, op=head.op,
+                         lanes=len(batch))
+        with _obs.span("bucket_feed", trace_id=head.trace_id, op=head.op,
+                       lanes=len(batch)):
+            self._execute_batch(batch, prepared)
 
     def _prep_loop(self) -> None:
         """Double-buffer producer: pack/pad the next bucket while the
@@ -979,8 +1041,7 @@ class BatchingEngine:
                 batch, _ = self._take_batch_locked()
             if batch:
                 try:
-                    _staging_put(self._staging,
-                                 (batch, self._prepare(batch)))
+                    _staging_put(self._staging, self._stage(batch))
                 except Exception:  # noqa: BLE001 — staging drop/chaos
                     # A dropped staging put must not lose requests: the
                     # batch goes back to the FRONT of the admission
@@ -992,31 +1053,41 @@ class BatchingEngine:
                         self._work.notify()
         self._staging.put(None)  # sentinel: feed thread drains then exits
 
-    def _worker_loop(self) -> None:
-        if self.opt.double_buffer:
-            while True:
-                try:
-                    item = self._staging.get(
-                        timeout=self.opt.poll_interval_s)
-                except queue_mod.Empty:
-                    self.heartbeats.beat(0)
-                    continue
-                if item is None:
-                    return
-                batch, prepared = item
+    def _next_staged(self) -> Optional[tuple]:
+        """The next bucket the prep thread staged; None once it stops."""
+        while True:
+            try:
+                return self._staging.get(timeout=self.opt.poll_interval_s)
+            except queue_mod.Empty:
                 self.heartbeats.beat(0)
-                self._execute_batch(batch, prepared)
-            return
+
+    def _next_taken(self) -> Optional[list]:
+        """Single-buffered: the next batch taken from the admission
+        queue on this thread; None once the engine stops."""
         while True:
             with self._work:
                 while self._running and not self._queue:
                     self._work.wait(self.opt.poll_interval_s)
                 if not self._running and not self._queue:
-                    return
+                    return None
                 batch, _ = self._take_batch_locked()
-            self.heartbeats.beat(0)
             if batch:
-                self._execute_batch(batch)
+                return batch
+            self.heartbeats.beat(0)
+
+    def _worker_loop(self) -> None:
+        staged = self.opt.double_buffer
+        while True:
+            with _obs.span("feed_wait") as wait:
+                item = self._next_staged() if staged else self._next_taken()
+                if item is not None:
+                    batch = item[0] if staged else item
+                    wait.set(trace_id=batch[0].trace_id, op=batch[0].op,
+                             lanes=len(batch))
+            if item is None:
+                return
+            self.heartbeats.beat(0)
+            self._feed(*(item if staged else self._stage(item)))
 
     # -- supervision --------------------------------------------------------
 

@@ -9,7 +9,9 @@ survives an 8-thread hammer with exact final counts (chaos marker).
 Also the ``telemetry.delta()`` mid-window-counter regression.
 """
 
+import gc
 import json
+import sys
 import threading
 import time
 import warnings
@@ -25,7 +27,8 @@ from repro.core.static_registry import StaticPlanRegistry
 from repro.core.tuning import TuningTable
 from repro.obs import drift as drift_mod
 from repro.obs import tracing
-from repro.serve.batching import BatchingEngine, BatchingOptions
+from repro.serve.batching import (BatchingEngine, BatchingOptions,
+                                  encode_aead_record)
 
 
 @pytest.fixture(autouse=True)
@@ -365,6 +368,190 @@ class TestServingTrace:
         req.result(timeout=60)
         assert req.trace_id is None
         assert obs.finished_spans() == []
+
+
+# ---------------------------------------------------------------------------
+# Bucket lifecycle on the feed thread
+# ---------------------------------------------------------------------------
+
+PHASES = ("bucket_wait", "feed_wait", "bucket_feed", "bucket_pack",
+          "bucket_launch", "bucket_sync", "bucket_unpack")
+PREP, FEED = "batching-host-prep", "batching-device-feed"
+AEAD_KEY = bytes(range(16))
+
+
+@pytest.fixture(scope="module")
+def served_spans():
+    """A threaded, double-buffered engine on the megakernel serves a few
+    SHA3 and GCM buckets with recording on: its spans and requests."""
+    was = obs.enabled()
+    obs.enable()
+    obs.reset()
+    try:
+        eng = BatchingEngine(BatchingOptions(
+            max_batch=4, chain=("megakernel",), aead_key=AEAD_KEY))
+        reqs = [eng.submit(b"m%d" % i) for i in range(6)]
+        reqs += [eng.submit(encode_aead_record(bytes([i]) * 12, b"x" * 16,
+                                               b"hdr"), op="gcm_seal")
+                 for i in range(5)]
+        for r in reqs:
+            r.result(timeout=600)
+        eng.close()
+        spans = obs.finished_spans()
+    finally:
+        (obs.enable if was else obs.disable)()
+        obs.reset()
+    return spans, reqs
+
+
+def _by_name(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+class TestBucketLifecycle:
+    def test_each_bucket_has_one_span_of_each_phase(self, served_spans):
+        spans, reqs = served_spans
+        feeds = _by_name(spans, "bucket_feed")
+        assert {f.attrs["op"] for f in feeds} == {"sha3_256", "gcm_seal"}
+        assert sum(f.attrs["lanes"] for f in feeds) == len(reqs)
+        for f in feeds:
+            op = f.attrs["op"]
+            mine = {n: [s for s in _by_name(spans, n)
+                        if s.trace_id == f.trace_id] for n in PHASES}
+            assert {n: len(v) for n, v in mine.items()} == dict.fromkeys(
+                PHASES, 1), op
+            for name, (sp,) in mine.items():
+                on_prep = name == "bucket_pack" and op == "sha3_256"
+                assert sp.thread_name == (PREP if on_prep else FEED), name
+                assert sp.attrs["op"] == op
+                assert sp.attrs["lanes"] == f.attrs["lanes"]
+            # The feed thread's phases follow one another inside it.
+            inner = ["bucket_launch", "bucket_sync", "bucket_unpack"]
+            if op == "gcm_seal":
+                inner.insert(0, "bucket_pack")
+            seq = [mine[n][0] for n in inner]
+            assert f.t0 <= seq[0].t0
+            assert all(a.t1 <= b.t0 for a, b in zip(seq, seq[1:]))
+            assert seq[-1].t1 <= f.t1
+            assert mine["feed_wait"][0].t1 <= f.t0
+
+    def test_request_phases_follow_and_add_up(self, served_spans):
+        spans, reqs = served_spans
+        feeds = {s.trace_id: s for s in _by_name(spans, "bucket_feed")}
+        qw = {s.trace_id: s for s in _by_name(spans, "queue_wait")}
+        # A bucket's spans carry its first request's trace id, and every
+        # request of the bucket was taken at the same instant.
+        head_at = {qw[t].t1: t for t in feeds}
+        for r in reqs:
+            (request,) = [s for s in _by_name(spans, "request")
+                          if s.trace_id == r.trace_id]
+            wait = qw[r.trace_id]
+            head = head_at[wait.t1]
+
+            def phase(name):
+                (sp,) = [s for s in _by_name(spans, name)
+                         if s.trace_id == head]
+                return sp
+
+            chain = [wait]
+            if r.op == "sha3_256":          # packed on the prep thread
+                chain.append(phase("bucket_pack"))
+            chain += [phase("bucket_wait"), feeds[head]]
+            assert all(a.t1 <= b.t0 for a, b in zip(chain, chain[1:]))
+            assert chain[0].t0 == request.t0
+            assert request.t1 <= chain[-1].t1
+            total = sum(s.t1 - s.t0 for s in chain)
+            assert total == pytest.approx(request.t1 - request.t0,
+                                          rel=0.05)
+
+    def test_disabled_builds_no_annotation_and_records_nothing(
+            self, monkeypatch):
+        import jax.profiler
+
+        built = []
+
+        class Probe:
+            def __init__(self, name, **kw):
+                built.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                pass
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Probe)
+        eng = BatchingEngine(BatchingOptions(max_batch=2), start=False)
+        obs.disable()
+        req = eng.submit(b"quiet")
+        while eng.run_once():
+            pass
+        req.result(timeout=60)
+        assert built == [] and obs.finished_spans() == []
+        obs.enable()
+        req = eng.submit(b"heard")
+        while eng.run_once():
+            pass
+        req.result(timeout=60)
+        assert {"bucket_launch", "bucket_sync", "bucket_unpack",
+                "bucket_pack"} <= set(built)
+        assert set(built) <= tracing.PROFILER_SPANS
+
+    def test_gc_pause_recorded_while_enabled_only(self):
+        obs.enable()
+        assert tracing._gc_hook in gc.callbacks
+        gc.collect()
+        (pause,) = _by_name(obs.finished_spans(), "gc_pause")
+        assert pause.thread_name == threading.current_thread().name
+        assert pause.t1 >= pause.t0
+        obs.disable()
+        assert tracing._gc_hook not in gc.callbacks
+        obs.reset()
+        gc.collect()
+        assert obs.finished_spans() == []
+
+    def test_gc_pauses_filed_under_thread_churn(self):
+        """With many threads recording spans while collections run
+        at nearly every allocation, every span is filed and each
+        collection of the oldest generation leaves one ``gc_pause``."""
+        obs.enable()
+        n_threads, n_iter, every = 16, 200, 50
+        full0 = gc.get_stats()[2]["collections"]
+        switch, thresholds = sys.getswitchinterval(), gc.get_threshold()
+        sys.setswitchinterval(1e-6)
+        gc.set_threshold(1, 1, 1)   # collections at nearly any allocation
+        try:
+            def work():
+                for i in range(n_iter):
+                    with obs.span("churn"):
+                        junk = [[j] for j in range(20)]  # noqa: F841
+                    if i % every == 0:
+                        gc.collect()
+
+            threads = [threading.Thread(target=work)
+                       for _ in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(switch)
+            gc.set_threshold(*thresholds)
+        spans = obs.finished_spans()
+        full = gc.get_stats()[2]["collections"] - full0
+        assert len(_by_name(spans, "churn")) == n_threads * n_iter
+        assert len(_by_name(spans, "gc_pause")) == full > 0
+
+    def test_set_adopts_a_trace_id(self):
+        obs.enable()
+        with obs.span("feed_wait") as sp:
+            sp.set(trace_id=12345, op="sha3_256")
+        assert sp.trace_id == 12345 and sp.attrs == {"op": "sha3_256"}
+        obs.disable()
+        with obs.span("feed_wait") as null:
+            null.set(trace_id=1)
+        assert null.trace_id is None
 
 
 # ---------------------------------------------------------------------------

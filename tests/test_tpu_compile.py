@@ -87,3 +87,12 @@ def test_megakernel_keccak_compiles(one_chip):
 def test_megakernel_gcm_seal_compiles(one_chip):
     _, program, _ = gcm.gcm_program(GCM_KEY, 1024, 16)
     _compile_program(program, 128, one_chip)
+
+
+def test_megakernel_custom_call_is_named(one_chip):
+    """The profile names the megakernel's device operation after the
+    kernel (``%plan_program.N = ... custom-call(``), not ``%_unknown_``."""
+    text = _compile_program(keccak.megakernel_program(), 128,
+                            one_chip).as_text()
+    calls = [ln for ln in text.splitlines() if " custom-call(" in ln]
+    assert calls and all("%plan_program" in ln for ln in calls), calls
