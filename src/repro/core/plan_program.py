@@ -391,22 +391,15 @@ def clear_program_cache() -> None:
 def _control_digest(program: "PlanProgram") -> str:
     """Digest of the control content a cached executable was built from
     (step stream, constants, plan idx/weight arrays).  The kernel owns
-    the digest recipe so the opcode numbering salts it."""
+    the digest recipe so the opcode numbering and the dense-plan rule
+    salt it: the dense tables are a function of the sealed plan arrays
+    and step stream under that rule, as the entry list is."""
     from repro.kernels import plan_program_kernel as ppk  # lazy: kernels opt.
     parts = []
     for plan in program.plans:
         parts.append(plan.idx)
         parts.append(plan.weights)
     return ppk.control_digest(encode_steps(program), program.consts, parts)
-
-
-def _pad_axis(x, mult, axis, value=0):
-    pad = (-x.shape[axis]) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
 
 
 def _plan_fold(plan: xb.PermutePlan) -> str:
@@ -428,33 +421,93 @@ def encode_steps(program: PlanProgram) -> np.ndarray:
     return np.asarray(rows, np.int32)
 
 
-def _encode_plans(program: PlanProgram, ppk) -> tuple:
-    """The program's plans as the kernel's flat select-entry list.
-
-    Every live select ``idx[i, j]`` (0 <= src < n) becomes one
-    ``(dst=i, src, weight)`` triple (weight 1 for unweighted plans);
-    DROP and out-of-range selects contribute nothing, so they are not
-    encoded.  Each plan's run starts on an HBM_ALIGN-word boundary and
-    the list carries one ENTRY_CHUNK of tail padding, so the kernel's
-    fixed-size chunk DMAs stay in bounds.  Returns (entries, meta) with
-    meta = per plan (word offset, entry count, GF(2) XOR fold, 0).
-    """
-    runs, meta, off = [], [], 0
+def _live_entries(program: PlanProgram) -> list:
+    """Per plan: its live selects (0 <= src < n) as (dst, src, weight)
+    int32 arrays, weight 1 for unweighted plans.  DROP and out-of-range
+    selects contribute nothing, so they are not encoded."""
+    out = []
     for plan in program.plans:
         idx = np.asarray(plan.idx, np.int32)
-        live = (idx >= 0) & (idx < program.n)
-        dst, col = np.nonzero(live)
+        dst, col = np.nonzero((idx >= 0) & (idx < program.n))
         w = (np.ones(dst.shape, np.int32) if plan.weights is None
              else np.asarray(plan.weights, np.int32)[dst, col])
-        run = np.stack([dst.astype(np.int32), idx[dst, col], w],
-                       axis=1).reshape(-1)
+        out.append((dst.astype(np.int32), idx[dst, col], w))
+    return out
+
+
+def _plan_uses(program: PlanProgram) -> list:
+    """Per plan: the PERMUTE steps of one round that apply it."""
+    uses = [0] * len(program.plans)
+    for s in program.steps:
+        if s.op == PERMUTE:
+            uses[s.plan] += 1
+    return uses
+
+
+def dense_slots(program: PlanProgram) -> tuple:
+    """Per plan: its dense table slot, or -1 where the megakernel walks
+    its select entries.
+
+    A plan runs as one MXU product when its semiring is GF2 (XOR fold),
+    it has at least ``DENSE_MIN_SELECTS_PER_ROW`` live selects per state
+    row, and its table fits: plans are taken in order of live entries x
+    uses, each once, while the tables stay within
+    ``DENSE_VMEM_BUDGET_BYTES`` and, with a 128-lane register file of
+    4-byte words, within ``VMEM_CAP_BYTES``.  REAL plans and sparse GF2
+    plans walk.  The choice reads only the plans and the step stream.
+    """
+    from repro.kernels import plan_program_kernel as ppk  # lazy: kernels opt.
+    n_mm = program.n + (-program.n) % ppk.LANES
+    table = ppk.dense_table_bytes(n_mm)
+    uses = _plan_uses(program)
+    cost = [len(e[0]) * u for e, u in zip(_live_entries(program), uses)]
+    slots = [-1] * len(program.plans)
+    taken = 0
+    for p in sorted(range(len(program.plans)), key=lambda p: -cost[p]):
+        if (program.plans[p].semiring is GF2 and uses[p]
+                and cost[p] >= ppk.DENSE_MIN_SELECTS_PER_ROW * program.n
+                * uses[p]
+                and (taken + 1) * table <= ppk.DENSE_VMEM_BUDGET_BYTES
+                and ppk.vmem_bytes(n_mm, ppk.LANES, program.n_regs, 4,
+                                   taken + 1) <= ppk.VMEM_CAP_BYTES):
+            slots[p] = taken
+            taken += 1
+    return tuple(slots)
+
+
+def _encode_plans(program: PlanProgram, slots: tuple, n_pad: int,
+                  ppk) -> tuple:
+    """The program's plans as the kernel's flat select-entry list, and
+    the dense plans' tables.
+
+    Every live select ``idx[i, j]`` becomes one ``(dst=i, src, weight)``
+    triple.  Each plan's run starts on an HBM_ALIGN-word boundary and
+    the list carries one ENTRY_CHUNK of tail padding, so the kernel's
+    fixed-size chunk DMAs stay in bounds.  A dense plan's table holds
+    ``T[i, s]`` = the parity of ``weight & 1`` over its entries
+    ``(i, s)``: what the walk's ``acc ^= (x[s] * w) & 1`` computes,
+    duplicates and even weights included.  Returns (entries, meta,
+    tables) with meta = per plan (word offset, entry count, GF(2) XOR
+    fold, dense slot or -1), and tables None when no plan is dense.
+    """
+    runs, meta, off = [], [], 0
+    n_dense = max(slots, default=-1) + 1
+    tables = (np.zeros((n_dense, n_pad, n_pad), np.uint8) if n_dense
+              else None)
+    for plan, (dst, src, w), slot in zip(program.plans,
+                                         _live_entries(program), slots):
+        if slot >= 0:
+            np.add.at(tables[slot], (dst, src), (w & 1).astype(np.uint8))
+        run = np.stack([dst, src, w], axis=1).reshape(-1)
         run = np.pad(run, (0, (-run.size) % ppk.HBM_ALIGN))
-        meta.append((off, dst.size, int(_plan_fold(plan) == "xor"), 0))
+        meta.append((off, dst.size, int(_plan_fold(plan) == "xor"), slot))
         runs.append(run)
         off += run.size
     runs.append(np.zeros(ppk.ENTRY_WORDS * ppk.ENTRY_CHUNK, np.int32))
-    meta = np.asarray(meta or [(0, 0, 0, 0)], np.int32).reshape(-1)
-    return np.concatenate(runs), meta
+    meta = np.asarray(meta or [(0, 0, 0, -1)], np.int32).reshape(-1)
+    if tables is not None:
+        tables = (tables & 1).astype(ppk.DENSE_DTYPE)
+    return np.concatenate(runs), meta, tables
 
 
 def _encode_consts(program: PlanProgram, n_pad: int, ppk) -> np.ndarray:
@@ -470,16 +523,18 @@ def _encode_consts(program: PlanProgram, n_pad: int, ppk) -> np.ndarray:
         padded.reshape(-1, ppk.LANES, n_pad).transpose(0, 2, 1))
 
 
-def encode_program(program: PlanProgram, n_pad: int) -> tuple:
-    """The megakernel's operands for one program at ``n_pad`` rows.
+def encode_program(program: PlanProgram) -> tuple:
+    """The megakernel's operands for one program.
 
     Control information is encoded once here: the step stream (padded
     to whole SMEM chunks), every plan's live selects as a flat
-    (dst, src, weight) entry list with per-plan offset/count/fold
-    metadata — the work of a PERMUTE is its live selects, not rows x k
-    of DROP padding — and the lane-transposed constants table.
-    Returns ``(control, static)``: the control arrays the kernel takes
-    after the state, and its static keyword arguments.
+    (dst, src, weight) entry list with per-plan offset/count/fold/slot
+    metadata — the work of a walked PERMUTE is its live selects, not
+    rows x k of DROP padding — the lane-transposed constants table,
+    and, when ``dense_slots`` picks any, the dense plans' 0/1 tables.
+    Returns ``(n_pad, control, static)``: the state's padded row count,
+    the control arrays the kernel takes after the state, and its static
+    keyword arguments.
     """
     from repro.kernels import plan_program_kernel as ppk  # lazy: kernels opt.
 
@@ -494,21 +549,48 @@ def encode_program(program: PlanProgram, n_pad: int) -> tuple:
     words = np.zeros((n_steps + (-n_steps) % ppk.STEP_CHUNK,
                       ppk.STEP_WORDS), np.int32)
     words[:n_steps, :steps.shape[1]] = steps
-    entries, meta = _encode_plans(program, ppk)
+    # Rows: n rounded up to ROW_TILE, or to 128 when a plan runs dense
+    # (the tables' lane dimension).  Padded rows read as zero.
+    slots = dense_slots(program)
+    mult = ppk.LANES if max(slots, default=-1) >= 0 else ppk.ROW_TILE
+    n_pad = program.n + (-program.n) % mult
+    entries, meta, tables = _encode_plans(program, slots, n_pad, ppk)
     control = (words.reshape(-1), entries, meta,
                _encode_consts(program, n_pad, ppk))
+    if tables is not None:
+        control += (tables,)
     static = dict(n_steps=n_steps, n_regs=program.n_regs,
                   rounds=program.rounds, const_stride=program.const_stride)
-    return control, static
+    return n_pad, control, static
 
 
-def _build_exec(program: PlanProgram, n_pad: int, interpret: bool):
-    """Megakernel closure for one (program, geometry) pair: the control
-    arrays go to the device once and ride as arguments of one jitted
-    launch (not as constants folded into the executable)."""
+@dataclasses.dataclass(frozen=True)
+class _Exec:
+    """One cached megakernel executable and what a launch of it does:
+    the state's padded rows, and the live select entries its PERMUTE
+    steps run as products and walk, rounds included."""
+
+    run: object
+    n_pad: int
+    entries_dense: int
+    entries_walked: int
+
+
+def _build_exec(program: PlanProgram, interpret: bool) -> _Exec:
+    """Megakernel closure for one program: the control arrays go to the
+    device once and ride as arguments of one jitted launch (not as
+    constants folded into the executable)."""
     from repro.kernels import plan_program_kernel as ppk  # lazy: kernels opt.
 
-    control, static = encode_program(program, n_pad)
+    n_pad, control, static = encode_program(program)
+    meta = control[2].reshape(-1, ppk.META_WORDS)
+    dense = walked = 0
+    for p, uses in enumerate(_plan_uses(program)):
+        n_entries = int(meta[p, 1]) * uses * program.rounds
+        if meta[p, 3] >= 0:
+            dense += n_entries
+        else:
+            walked += n_entries
     control = tuple(jnp.asarray(c) for c in control)
     launch = jax.jit(functools.partial(ppk.plan_program_pallas,
                                        interpret=interpret, **static))
@@ -516,19 +598,19 @@ def _build_exec(program: PlanProgram, n_pad: int, interpret: bool):
     def run(xp):
         return launch(xp, *control)
 
-    return run
+    return _Exec(run, n_pad, dense, walked)
 
 
 def _run_megakernel(program: PlanProgram, x2: Array,
                     interpret: Optional[bool]) -> Array:
     global _PROGRAM_LAUNCHES, _PASSES_AVOIDED
+    from repro.core import telemetry  # lazy: telemetry imports this module
     from repro.kernels import plan_program_kernel as ppk  # lazy: kernels opt.
     from repro.kernels.ops import default_interpret
     interpret = default_interpret(interpret)
     n, d = x2.shape
-    n_pad = n + (-n) % ppk.ROW_TILE
     d_pad = d + (-d) % ppk.LANES
-    key = (id(program), n_pad, d_pad, str(x2.dtype), bool(interpret))
+    key = (id(program), d_pad, str(x2.dtype), bool(interpret))
     hit = _EXEC_CACHE.get(key)
     cache_hit = hit is not None and hit[0] is program
     if cache_hit:
@@ -541,24 +623,28 @@ def _run_megakernel(program: PlanProgram, x2: Array,
             evict=lambda: _EXEC_CACHE.pop(key, None))
         _EXEC_STATS["hits"] += 1
         _EXEC_CACHE.move_to_end(key)
-        run = hit[1]
+        ex = hit[1]
     else:
         _EXEC_STATS["misses"] += 1
-        run = _build_exec(program, n_pad, interpret)
+        ex = _build_exec(program, interpret)
         _integrity.PROGRAM_GUARD.seal(
             key, digest=_control_digest(program))
-        _EXEC_CACHE[key] = (program, run)
+        _EXEC_CACHE[key] = (program, ex)
         while len(_EXEC_CACHE) > _EXEC_CACHE_CAPACITY:
             evicted_key, _ = _EXEC_CACHE.popitem(last=False)
             _integrity.PROGRAM_GUARD.drop(evicted_key)
     with _COUNT_LOCK:
         _PROGRAM_LAUNCHES += 1
         _PASSES_AVOIDED += program.passes
-    xp = _pad_axis(_pad_axis(x2, ppk.ROW_TILE, 0), ppk.LANES, 1)
+    telemetry.incr("megakernel_entries_dense", ex.entries_dense)
+    telemetry.incr("megakernel_entries_walked", ex.entries_walked)
+    xp = x2
+    if (ex.n_pad, d_pad) != (n, d):
+        xp = jnp.pad(x2, ((0, ex.n_pad - n), (0, d_pad - d)))
     with _obs.span("program_launch", program=program.name,
                    passes=program.passes, n=n, d=d,
                    exec_cache_hit=cache_hit):
-        return run(xp)[:n, :d]
+        return ex.run(xp)[:n, :d]
 
 
 # ---------------------------------------------------------------------------

@@ -17,14 +17,22 @@ the resident registers:
   wiring, plan/const slot) are scalar reads, and the opcode selects
   its op body with ``pl.when`` — every body is compiled once, however
   many steps or rounds the program has;
-* a PERMUTE walks its plan's **select entries** — the flat list of
-  ``(dst row, src row, weight)`` triples of the plan's live selects,
-  DMA'd into SMEM a chunk at a time — and folds each gathered source
-  row into an accumulator register: integer XOR of bit 0 for GF(2)
-  (so bit states never touch the f32 datapath and the MXU's 2^24
-  exactness bound does not apply), wrapping add for REAL.  A row load
-  at a scalar offset is the gather the TPU's vector unit offers; the
-  work is the plan's live entries, never ``rows x k`` of DROP padding;
+* a PERMUTE's work is either its plan's live entries or one product:
+  - the **walk** goes over the plan's select entries — the flat list
+    of ``(dst row, src row, weight)`` triples of its live selects,
+    DMA'd into SMEM a chunk at a time — and folds each gathered source
+    row into an accumulator register: integer XOR of bit 0 for GF(2),
+    wrapping add for REAL.  A row load at a scalar offset is the gather
+    the TPU's vector unit offers; the work is the plan's live entries,
+    never ``rows x k`` of DROP padding, at a fixed cost per entry;
+  - a **dense** GF(2) plan (chosen by the encoder: at least
+    ``DENSE_MIN_SELECTS_PER_ROW`` live selects per state row, and its
+    table within ``DENSE_VMEM_BUDGET_BYTES``) runs as one MXU product
+    of its 0/1 matrix with bit 0 of the source register, parity-folded:
+    ``dst = (T @ (src & 1)) & 1``.  Row sums are at most ``n`` < 2^24,
+    so the f32 accumulation is exact, and the time is one product
+    whatever the plan's entry count.  The tables are DMA'd into VMEM
+    once per launch, on the first lane block;
 * the elementwise ops (XOR/AND/ANDN/ADD/ROTLV/XOR_CONST/EQ_CONST) run
   over the registers in row tiles; a constant row reaches them as a
   ``(rows, 1)`` column cut out of a lane-transposed constants block
@@ -46,6 +54,7 @@ launch has a fixed latency per lane block.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +73,7 @@ OPCODES = ("permute", "xor", "and", "andn", "add", "rotlv", "xor_const",
 # Encoded layouts.  HBM arrays are 1-D and DMA'd in chunks whose start
 # and size are multiples of 1024 words (the 1-D HBM tiling).
 STEP_WORDS = 8           # (op, dst, a, b, plan, const, 0, 0)
+META_WORDS = 4           # per plan: (offset, count, xor fold, dense slot)
 STEP_CHUNK = 128         # steps per SMEM chunk (1024 words)
 ENTRY_WORDS = 3          # (dst row, src row, weight)
 ENTRY_CHUNK = 2048       # entries per SMEM chunk (6144 words, 24 KiB)
@@ -73,40 +83,69 @@ LANES = 128
 # Scoped VMEM the kernel may ask for: the TPU v5e core has 128 MiB.
 VMEM_CAP_BYTES = 128 * 1024 * 1024
 _VMEM_SLACK_BYTES = 4 * 1024 * 1024
+# Dense GF(2) PERMUTEs.  A walked entry costs about 13 ns on a v5e at
+# 128 lanes, whatever the plan (12.7 ns Keccak-f, 13.0 ns GCM seal).
+# One product of an (n x n) 0/1 table with the (n x 128) bit block is
+# 256 n^2 MXU operations: 5.2e-12 n^2 s at a quarter of the 197 TFLOP/s
+# bf16 peak.  The product wins once a plan has 4e-4 n live selects per
+# row: 0.7 at n = 1664, 1.1 at n = 2688, 2.0 at n = 5,000 (the largest
+# table the budget below admits).  Four per row keeps twice that margin.
+DENSE_MIN_SELECTS_PER_ROW = 4
+# VMEM the dense tables of one program may take, out of VMEM_CAP_BYTES:
+# two GCM tables (2 x 14.45 MB at n = 2688) with room for a third.
+DENSE_VMEM_BUDGET_BYTES = 48 * 1024 * 1024
+DENSE_DTYPE = jnp.bfloat16   # 0/1 exact; the MXU's native input
+DENSE_ROWS = 128             # output rows per product tile
 
 
 def control_digest(steps, consts, plan_parts=()) -> str:
     """Content digest of one program's kernel-visible control state:
     the encoded step stream, the constants table, and the per-plan
-    idx/weight arrays, salted with the opcode numbering so a reordered
-    OPCODES tuple invalidates every sealed digest rather than letting
-    an old stream verify against a renumbered switch."""
+    idx/weight arrays, from which the entry list and the dense tables
+    are built.  Salted with the opcode numbering and the dense-plan
+    rule, so a reordered OPCODES tuple or another rule invalidates every
+    sealed digest rather than letting an old stream verify against a
+    renumbered switch or differently chosen tables."""
     from repro.core import integrity
+    rule = (f"dense>={DENSE_MIN_SELECTS_PER_ROW}/row,"
+            f"{DENSE_VMEM_BUDGET_BYTES}B,{jnp.dtype(DENSE_DTYPE).name}")
     return integrity.content_digest(
-        ("|".join(OPCODES), steps, consts) + tuple(plan_parts))
+        ("|".join(OPCODES), rule, steps, consts) + tuple(plan_parts))
+
+
+def dense_table_bytes(n_pad: int) -> int:
+    """VMEM (and HBM) bytes of one dense plan's (n_pad, n_pad) table."""
+    return n_pad * n_pad * jnp.dtype(DENSE_DTYPE).itemsize
 
 
 def vmem_bytes(n_pad: int, lane_block: int, n_regs: int,
-               itemsize: int) -> int:
+               itemsize: int, n_dense: int = 0) -> int:
     """VMEM one launch asks for: the register file plus the PERMUTE
-    accumulator at ``lane_block`` lanes, and one constants block."""
-    return ((n_regs + 1) * n_pad * lane_block * itemsize
+    accumulator at ``lane_block`` lanes, one constants block, and with
+    ``n_dense`` dense plans their tables and the source's bit block."""
+    need = ((n_regs + 1) * n_pad * lane_block * itemsize
             + n_pad * LANES * 4 + _VMEM_SLACK_BYTES)
+    if n_dense:
+        need += (n_dense * dense_table_bytes(n_pad)
+                 + n_pad * lane_block * jnp.dtype(DENSE_DTYPE).itemsize)
+    return need
 
 
-def lane_block(n_pad: int, d_pad: int, n_regs: int, itemsize: int) -> int:
+def lane_block(n_pad: int, d_pad: int, n_regs: int, itemsize: int,
+               n_dense: int = 0) -> int:
     """The widest lane block (a multiple of 128 dividing ``d_pad``, at
-    most 1024) whose register file fits the VMEM cap.  Raises when not
-    even 128 lanes fit: the state is too tall for one core's VMEM."""
+    most 1024) whose register file and dense tables fit the VMEM cap.
+    Raises when not even 128 lanes fit: the state is too tall for one
+    core's VMEM."""
     for q in (8, 4, 2, 1):
         blk = LANES * q
-        if (d_pad % blk == 0 and vmem_bytes(n_pad, blk, n_regs, itemsize)
-                <= VMEM_CAP_BYTES):
+        if (d_pad % blk == 0 and vmem_bytes(n_pad, blk, n_regs, itemsize,
+                                            n_dense) <= VMEM_CAP_BYTES):
             return blk
     raise ValueError(
         f"plan program state of {n_pad} rows x {n_regs} registers needs "
-        f"{vmem_bytes(n_pad, LANES, n_regs, itemsize)} bytes of VMEM at "
-        f"{LANES} lanes; the cap is {VMEM_CAP_BYTES}")
+        f"{vmem_bytes(n_pad, LANES, n_regs, itemsize, n_dense)} bytes of "
+        f"VMEM at {LANES} lanes; the cap is {VMEM_CAP_BYTES}")
 
 
 def _rotlv(v, amt):
@@ -116,10 +155,18 @@ def _rotlv(v, amt):
     return (v << amt) | (v >> ((bits - amt) & (bits - 1)))
 
 
-def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, o_hbm,
-            regs, acc, cblk, step_buf, ent_buf, cur_blk, *,
-            n_steps, n_regs, rounds, const_stride):
-    """The VM over one lane block: rounds { step chunks { steps } }."""
+def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, *refs,
+            n_steps, n_regs, rounds, const_stride, n_dense):
+    """The VM over one lane block: rounds { step chunks { steps } }.
+
+    With ``n_dense`` dense plans, ``refs`` holds their tables' HBM
+    operand before the output and, after the common scratch, the tables'
+    VMEM copy and the bf16 bit block of a product's source."""
+    if n_dense:
+        (dense_hbm, o_hbm, regs, acc, cblk, step_buf, ent_buf, cur_blk,
+         tables, bits) = refs
+    else:
+        o_hbm, regs, acc, cblk, step_buf, ent_buf, cur_blk = refs
     n_pad, width = acc.shape
     dtype = acc.dtype
     n_tiles = n_pad // ROW_TILE
@@ -130,6 +177,11 @@ def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, o_hbm,
             body(pl.ds(pl.multiple_of(t * ROW_TILE, ROW_TILE), ROW_TILE))
             return carry
         jax.lax.fori_loop(0, n_tiles, run, 0)
+
+    if n_dense:
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            pltpu.sync_copy(dense_hbm, tables)
 
     pltpu.sync_copy(x_hbm.at[:, lanes], regs.at[0])
     for r in range(1, n_regs):
@@ -157,10 +209,10 @@ def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, o_hbm,
             return col.astype(dtype)
         return column
 
-    def permute(dst, a, p):
-        off = meta_ref[4 * p]
-        count = meta_ref[4 * p + 1]
-        is_xor = meta_ref[4 * p + 2]
+    def walk(dst, a, p):
+        off = meta_ref[META_WORDS * p]
+        count = meta_ref[META_WORDS * p + 1]
+        is_xor = meta_ref[META_WORDS * p + 2]
 
         def zero(rows):
             acc[rows, :] = jnp.zeros((ROW_TILE, width), dtype)
@@ -198,6 +250,37 @@ def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, o_hbm,
         def store(rows):
             regs[dst, rows, :] = acc[rows, :]
         tiles(store)
+
+    def product(dst, a, slot):
+        """dst = (T[slot] @ (regs[a] & 1)) & 1 over every row: the
+        source's bits are copied out first, so ``dst`` may be ``a``."""
+        def to_bits(rows):
+            bit = (regs[a, rows, :] & 1).astype(jnp.int32)
+            bits[rows, :] = bit.astype(jnp.float32).astype(DENSE_DTYPE)
+        tiles(to_bits)
+
+        def body(t, carry):
+            rows = pl.ds(pl.multiple_of(t * DENSE_ROWS, DENSE_ROWS),
+                         DENSE_ROWS)
+            y = jnp.dot(tables[slot, rows, :], bits[...],
+                        preferred_element_type=jnp.float32)
+            regs[dst, rows, :] = (y.astype(jnp.int32) & 1).astype(dtype)
+            return carry
+        jax.lax.fori_loop(0, n_pad // DENSE_ROWS, body, 0)
+
+    def permute(dst, a, p):
+        if not n_dense:
+            walk(dst, a, p)
+            return
+        slot = meta_ref[META_WORDS * p + 3]
+
+        @pl.when(slot < 0)
+        def _():
+            walk(dst, a, p)
+
+        @pl.when(slot >= 0)
+        def _():
+            product(dst, a, slot)
 
     def elementwise(dst, a, b, fn):
         def body(rows):
@@ -262,6 +345,7 @@ def plan_program_pallas(
     entries: jax.Array,
     meta: jax.Array,
     consts: jax.Array,
+    dense: Optional[jax.Array] = None,
     *,
     n_steps: int,
     n_regs: int,
@@ -272,35 +356,45 @@ def plan_program_pallas(
     """Raw megakernel entry; operands come from ``encode_*`` in
     ``core.plan_program``.
 
-    state: (n_pad, d_pad), n_pad a multiple of ROW_TILE and d_pad of
-    128; steps: flat int32, STEP_WORDS per step, padded to whole
-    STEP_CHUNKs; entries: flat int32 (dst, src, weight) triples, each
-    plan's run starting at a multiple of HBM_ALIGN words, with one
-    ENTRY_CHUNK of tail padding; meta: (4 * n_plans,) int32 per-plan
-    (entry offset, entry count, 1 = GF(2) XOR fold, 0); consts:
-    (n_blocks, n_pad, 128) int32, constant ``c`` in lane ``c % 128`` of
-    block ``c // 128``.  Returns (n_pad, d_pad) in state.dtype.
+    state: (n_pad, d_pad), n_pad a multiple of ROW_TILE (of 128 with
+    dense plans) and d_pad of 128; steps: flat int32, STEP_WORDS per
+    step, padded to whole STEP_CHUNKs; entries: flat int32 (dst, src,
+    weight) triples, each plan's run starting at a multiple of HBM_ALIGN
+    words, with one ENTRY_CHUNK of tail padding; meta: (META_WORDS *
+    n_plans,) int32 per-plan (entry offset, entry count, 1 = GF(2) XOR
+    fold, dense slot or -1 to walk); consts: (n_blocks, n_pad, 128)
+    int32, constant ``c`` in lane ``c % 128`` of block ``c // 128``;
+    dense: None, or (n_dense, n_pad, n_pad) DENSE_DTYPE 0/1 tables, one
+    per dense slot.  Returns (n_pad, d_pad) in state.dtype.
     """
     n_pad, d_pad = state.shape
-    width = lane_block(n_pad, d_pad, n_regs, state.dtype.itemsize)
+    n_dense = 0 if dense is None else dense.shape[0]
+    itemsize = state.dtype.itemsize
+    width = lane_block(n_pad, d_pad, n_regs, itemsize, n_dense)
     kernel = functools.partial(
         _kernel, n_steps=n_steps, n_regs=n_regs, rounds=rounds,
-        const_stride=const_stride)
+        const_stride=const_stride, n_dense=n_dense)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    scratch = [
+        pltpu.VMEM((n_regs, n_pad, width), state.dtype),
+        pltpu.VMEM((n_pad, width), state.dtype),
+        pltpu.VMEM((n_pad, LANES), jnp.int32),
+        pltpu.SMEM((STEP_WORDS * STEP_CHUNK,), jnp.int32),
+        pltpu.SMEM((ENTRY_WORDS * ENTRY_CHUNK,), jnp.int32),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
+    operands = [meta, steps, entries, consts, state]
+    if n_dense:
+        scratch += [pltpu.VMEM(dense.shape, DENSE_DTYPE),
+                    pltpu.VMEM((n_pad, width), DENSE_DTYPE)]
+        operands.append(dense)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(d_pad // width,),
-        in_specs=[hbm, hbm, hbm, hbm],
+        in_specs=[hbm] * (len(operands) - 1),
         out_specs=hbm,
-        scratch_shapes=[
-            pltpu.VMEM((n_regs, n_pad, width), state.dtype),
-            pltpu.VMEM((n_pad, width), state.dtype),
-            pltpu.VMEM((n_pad, LANES), jnp.int32),
-            pltpu.SMEM((STEP_WORDS * STEP_CHUNK,), jnp.int32),
-            pltpu.SMEM((ENTRY_WORDS * ENTRY_CHUNK,), jnp.int32),
-            pltpu.SMEM((1,), jnp.int32),
-        ])
-    need = vmem_bytes(n_pad, width, n_regs, state.dtype.itemsize)
+        scratch_shapes=scratch)
+    need = vmem_bytes(n_pad, width, n_regs, itemsize, n_dense)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
@@ -311,4 +405,4 @@ def plan_program_pallas(
                                  VMEM_CAP_BYTES)),
         interpret=interpret,
         name="plan_program",
-    )(meta, steps, entries, consts, state)
+    )(*operands)

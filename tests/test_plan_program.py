@@ -264,6 +264,168 @@ class TestTelemetry:
         assert pp.program_cache_info()["misses"] == 2
 
 
+def _gf2_dense_program(n=200, k=6, seed=21):
+    """A GF(2) plan with duplicate sources, DROP selects, and even,
+    odd and negative weights, applied twice (once in place)."""
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, n, (n, k))
+    idx[:, 1] = idx[:, 0]                      # a duplicate in every row
+    idx[::7, -1] = pa.DROP
+    w = rng.integers(-3, 5, (n, k))
+    plan = xb.gather_plan(jnp.asarray(idx, jnp.int32), n,
+                          weights=jnp.asarray(w, jnp.int32), semiring=GF2)
+    b = pp.ProgramBuilder("gf2_dense", n, n_regs=2)
+    b.permute(1, 0, plan)
+    b.xor(0, 0, 1)
+    b.permute(0, 0, plan)
+    return b.build()
+
+
+def _permute_plans(program):
+    """The plan slot of each PERMUTE step, in step order."""
+    return [s.plan for s in program.steps if s.op == pp.PERMUTE]
+
+
+def _dense_gf2_plans(n, uses):
+    """A program of len(uses) distinct GF(2) plans of 4 live selects a
+    row, plan ``i`` applied ``uses[i]`` times."""
+    rng = np.random.default_rng(5)
+    b = pp.ProgramBuilder("budget", n, n_regs=2)
+    for u in uses:
+        plan = xb.gather_plan(
+            jnp.asarray(rng.integers(0, n, (n, 4)), jnp.int32), n,
+            semiring=GF2)
+        for _ in range(u):
+            b.permute(0, 0, plan)
+    return b.build()
+
+
+class TestDensePermute:
+    """Dense GF(2) PERMUTEs (one MXU product) against the chained
+    executor, the rule that picks them, and the launch counters."""
+
+    def test_keccak_full_program(self):
+        prog = kk.megakernel_program()
+        assert pp.dense_slots(prog) == (0, -1, -1)
+        x = _bits(31, (1600, 5))
+        np.testing.assert_array_equal(
+            np.asarray(pp.run_program(prog, x, backend="chained")),
+            np.asarray(pp.run_program(prog, x, backend="megakernel")))
+
+    @pytest.mark.parametrize("open_mode", [False, True])
+    @pytest.mark.parametrize("pt_len,aad_len", [(16, 16), (17, 5)])
+    def test_gcm_programs(self, pt_len, aad_len, open_mode):
+        """Seal and open, m=1 and m=2 with a 1-byte last block."""
+        _, prog, _ = gcm.gcm_program(bytes(range(16)), pt_len, aad_len,
+                                     open_mode=open_mode)
+        assert max(pp.dense_slots(prog)) >= 0
+        x = _bits(32, (prog.n, 3))
+        np.testing.assert_array_equal(
+            np.asarray(pp.run_program(prog, x, backend="chained",
+                                      pass_backend="reference")),
+            np.asarray(pp.run_program(prog, x, backend="megakernel")))
+
+    @pytest.mark.parametrize("values", ["bits", "int32", "uint32"])
+    def test_random_gf2_plan_parity(self, values):
+        """Duplicates, even weights and wide state values: only bit 0
+        of each product counts, as in the walk.  n = 200 is not a
+        multiple of 128; 300 lanes are three lane blocks."""
+        prog = _gf2_dense_program()
+        assert pp.dense_slots(prog) == (0,)
+        rng = np.random.default_rng(33)
+        if values == "bits":
+            x = jnp.asarray(rng.integers(0, 2, (200, 300)), jnp.int32)
+        elif values == "int32":
+            x = jnp.asarray(rng.integers(-1000, 1000, (200, 300)),
+                            jnp.int32)
+        else:
+            x = _words(34, (200, 300))
+        chained = pp.run_program(prog, x, backend="chained",
+                                 pass_backend="reference")
+        fused = pp.run_program(prog, x, backend="megakernel")
+        assert fused.dtype == x.dtype
+        np.testing.assert_array_equal(np.asarray(chained),
+                                      np.asarray(fused))
+
+    def test_table_is_weight_parity(self):
+        prog = _gf2_dense_program(n=8, k=5, seed=3)
+        n_pad, control, _ = pp.encode_program(prog)
+        assert n_pad == 128 and control[-1].shape == (1, 128, 128)
+        idx = np.asarray(prog.plans[0].idx)
+        w = np.asarray(prog.plans[0].weights)
+        want = np.zeros((128, 128), np.int64)
+        for i in range(8):
+            for j in range(5):
+                if 0 <= idx[i, j] < 8:
+                    want[i, idx[i, j]] += w[i, j]
+        np.testing.assert_array_equal(
+            np.asarray(control[-1][0], np.float32), want % 2)
+
+    def test_selection_rule(self):
+        keccak = kk.megakernel_program()
+        steps = _permute_plans(keccak)       # theta-rho-pi, chi, chi
+        assert [pp.dense_slots(keccak)[p] for p in steps] == [0, -1, -1]
+        assert keccak.plans[steps[1]].semiring is not GF2
+
+        _, seal, _ = gcm.gcm_program(bytes(range(16)), 17, 5)
+        slots = pp.dense_slots(seal)
+        plans = _permute_plans(seal)
+        # d1, d2, ctr, then round 1: nspread, psel, hirep, nfold, linear
+        d1, _, _, nspread, psel, hirep, nfold, linear = plans[:8]
+        absorbs = [s.plan for s in seal.steps
+                   if s.op == pp.PERMUTE and (s.dst, s.a) == (2, 1)]
+        full, masked = absorbs
+        assert seal.plans[nspread].semiring is not GF2
+        assert slots[psel] >= 0 and slots[full] >= 0
+        assert sorted(s for s in slots if s >= 0) == [0, 1]
+        for walked in (d1, nspread, hirep, nfold, linear, masked,
+                       plans[-1]):
+            assert slots[walked] == -1
+
+        chacha = cc.megakernel_program()
+        assert set(pp.dense_slots(chacha)) == {-1}
+        n_pad, control, _ = pp.encode_program(chacha)
+        assert n_pad == 64 and len(control) == 4
+
+    def test_sparse_and_unused_gf2_plans_walk(self):
+        rng = np.random.default_rng(2)
+        sparse = xb.gather_plan(
+            jnp.asarray(rng.integers(0, 64, (64, 3)), jnp.int32), 64,
+            semiring=GF2)
+        dense = xb.gather_plan(
+            jnp.asarray(rng.integers(0, 64, (64, 4)), jnp.int32), 64,
+            semiring=GF2)
+        b = pp.ProgramBuilder("t", 64, n_regs=2)
+        b.permute(1, 0, sparse)
+        b.plan_slot(dense)                  # in the table, never applied
+        assert pp.dense_slots(b.build()) == (-1, -1)
+
+    def test_budget_takes_plans_by_entries_times_uses(self):
+        """At n = 2688 three tables fit the budget and a fourth does
+        not: the plan applied least walks."""
+        prog = _dense_gf2_plans(2688, uses=[1, 3, 4, 2])
+        assert pp.dense_slots(prog) == (-1, 1, 0, 2)
+
+    def test_launch_counters(self):
+        keccak = kk.megakernel_program()
+        telemetry.reset()
+        with telemetry.delta() as d:
+            pp.run_program(keccak, _bits(0, (1600, 1)), backend="megakernel")
+        dd = d()
+        assert dd["megakernel_entries_dense"] == 17600 * 24
+        assert dd["megakernel_entries_walked"] == 2 * 1600 * 24
+
+        chacha = cc.megakernel_program()
+        with telemetry.delta() as d:
+            pp.run_program(chacha, _words(0, (16, 2)), backend="megakernel")
+            pp.run_program(chacha, _words(1, (16, 2)), backend="megakernel")
+        dd = d()
+        assert dd["megakernel_entries_dense"] == 0
+        live = sum(int(((np.asarray(chacha.plans[p].idx) >= 0)).sum())
+                   for p in _permute_plans(chacha))
+        assert dd["megakernel_entries_walked"] == 2 * 10 * live
+
+
 class TestKeccakMegakernel:
     def test_matches_per_round_path(self):
         bits = _bits(11, 1600)

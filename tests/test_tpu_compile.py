@@ -72,21 +72,37 @@ def test_sparse_crossbar_compiles(one_chip):
              _spec((N, D), jnp.float32, one_chip))
 
 
-def _compile_program(program, lanes, sharding):
-    n_pad = program.n + (-program.n) % ppk.ROW_TILE
-    control, static = pp.encode_program(program, n_pad)
+def _compile_program(program, lanes, sharding, n_dense=None):
+    """Compile ``program`` at ``lanes`` lanes.  Where ``n_dense`` is
+    given, check that as many dense plans ride as the tables operand and
+    that their VMEM need fits one block of ``lanes``."""
+    n_pad, control, static = pp.encode_program(program)
+    if n_dense is not None:
+        assert len(control) == 4 + bool(n_dense)
+    if n_dense:
+        assert control[-1].shape == (n_dense, n_pad, n_pad)
+        assert ppk.lane_block(n_pad, lanes, program.n_regs, 4,
+                              n_dense) == lanes
     fn = functools.partial(ppk.plan_program_pallas, **static)
     return _compile(fn, _spec((n_pad, lanes), jnp.int32, sharding),
                     *(_spec(c.shape, c.dtype, sharding) for c in control))
 
 
 def test_megakernel_keccak_compiles(one_chip):
-    _compile_program(keccak.megakernel_program(), 128, one_chip)
+    _compile_program(keccak.megakernel_program(), 128, one_chip, n_dense=1)
 
 
 def test_megakernel_gcm_seal_compiles(one_chip):
     _, program, _ = gcm.gcm_program(GCM_KEY, 1024, 16)
-    _compile_program(program, 128, one_chip)
+    # 8,800 rows: one table would pass the dense budget, so all walk.
+    _compile_program(program, 128, one_chip, n_dense=0)
+
+
+def test_megakernel_gcm_seal_dense_compiles(one_chip):
+    """The served TLS record geometry (17 B, 5 B header): the S-box and
+    full-block absorb plans run as products, at the widest lane block."""
+    _, program, _ = gcm.gcm_program(GCM_KEY, 17, 5)
+    _compile_program(program, 1024, one_chip, n_dense=2)
 
 
 def test_megakernel_custom_call_is_named(one_chip):
