@@ -249,7 +249,7 @@ def bench_seal(b, m, aad_len, *, iters, warmup):
         f"expected 1 launch per seal, saw {launches}/{n_calls}")
     assert xb.apply_call_count() - a0 == 0, \
         "fused seal leaked chained crossbar passes"
-    _, program, _ = gcm.gcm_program(KEY, 16 * m, aad_len)
+    _, program, _ = gcm.gcm_program(16 * m, aad_len)
 
     # -- chained per-block lowering (einsum) -------------------------------
     got = gcm.aes128_gcm_seal_batch(KEY, ivs, pts, aads, backend="einsum")

@@ -232,7 +232,7 @@ def inject_faults(*, seed: int = 0, compile_rate: float = 0.0,
                 f"seed {inj.seed})")
         return orig_compile(plan, **kw)
 
-    def mega_wrapper(program, x2, interpret):
+    def mega_wrapper(program, x2, keys, interpret):
         if inj.should_fire("corrupt"):
             corrupt_cache(corrupt_rng)
         if inj.should_fire("program"):
@@ -240,7 +240,7 @@ def inject_faults(*, seed: int = 0, compile_rate: float = 0.0,
                 f"injected megakernel launch failure "
                 f"(program call #{inj.calls['program'] - 1}, "
                 f"seed {inj.seed})")
-        return orig_mega(program, x2, interpret)
+        return orig_mega(program, x2, keys, interpret)
 
     xb.apply_plan = apply_wrapper
     xb.compile_plan = compile_wrapper

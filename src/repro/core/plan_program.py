@@ -26,11 +26,19 @@ Keccak-f[1600], per the ROADMAP).
                   one-hot *encode* primitive (a byte state compared to
                   row ``u`` is value ``u``'s indicator lane, so table
                   lookups become PERMUTE gathers in-register),
+* ``XOR_KEY``   — XOR a 128-row window with one block of the launch's
+                  per-lane *key operand* (AddRoundKey with a key per
+                  payload lane),
+* ``CLMUL``     — the carry-less product of a 128-row window of bits
+                  with a key-operand block, lane by lane: GF(2^128)
+                  multiplication by a per-lane factor before its fixed
+                  reduction, which an ordinary GF(2) PERMUTE does,
 
 over a small register file of ``(n, D)`` state buffers.  All control
 information — plans, constants, rotation amounts, the step list itself
-— is concrete program data; payload values never influence which steps
-run (the fixed-latency property, now checkable for a whole *schedule*
+— is concrete program data; payload values (key material included:
+the key operand is payload, one column per lane) never influence which
+steps run (the fixed-latency property, now checkable for a whole *schedule*
 via ``StaticPlanRegistry.register_program`` / ``program_fingerprint``).
 
 Two executors share the IR:
@@ -82,18 +90,30 @@ ADD = "add"              # dst = regs[a] + regs[b]        (wrapping)
 ROTLV = "rotlv"          # dst = rotl(regs[a], consts[const])  per-row
 XOR_CONST = "xor_const"  # dst = regs[a] ^ consts[const][:, None]
 EQ_CONST = "eq_const"    # dst = (regs[a] == consts[const][:, None])  0/1
+XOR_KEY = "xor_key"      # regs[a][row:row+128] ^= keys[key block]
+CLMUL = "clmul"          # regs[a][row:row+256] = regs[a][row:row+128]
+                         #   (x) keys[key block], carry-less, per lane
 
 _BINARY_OPS = (XOR, AND, ANDN, ADD)
-# EQ_CONST rides last so pre-existing encoded step streams (and the
+# New ops ride last so pre-existing encoded step streams (and the
 # kernel's switch branch numbering) keep their opcode values.
 _CONST_OPS = (ROTLV, XOR_CONST, EQ_CONST)
-OPS = (PERMUTE,) + _BINARY_OPS + _CONST_OPS
+_KEY_OPS = (XOR_KEY, CLMUL)
+OPS = (PERMUTE,) + _BINARY_OPS + _CONST_OPS + _KEY_OPS
+
+# The key operand is read in blocks of KEY_BLOCK rows (one 128-bit
+# value, a bit per row); XOR_KEY rewrites KEY_BLOCK rows of its
+# register and CLMUL 2 * KEY_BLOCK.
+KEY_BLOCK = 128
+_KEY_WINDOW = {XOR_KEY: KEY_BLOCK, CLMUL: 2 * KEY_BLOCK}
 
 
 @dataclasses.dataclass(frozen=True)
 class Step:
     """One program step.  ``a``/``b`` are register indices; ``plan`` and
-    ``const`` index the program's plan and constants tables."""
+    ``const`` index the program's plan and constants tables; ``key`` is
+    the key-operand block and ``row`` the first state row of the window
+    a key step rewrites in place (``dst == a``)."""
 
     op: str
     dst: int
@@ -101,6 +121,8 @@ class Step:
     b: int = -1
     plan: int = -1
     const: int = -1
+    key: int = -1
+    row: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,6 +204,24 @@ class PlanProgram:
                         f"[{step.const}, {last}] out of range ({n_consts} "
                         f"rows, stride {self.const_stride} x "
                         f"{self.rounds} rounds)")
+            if step.op in _KEY_OPS:
+                span = _KEY_WINDOW[step.op]
+                if (step.dst != step.a or step.key < 0 or step.row % 8
+                        or not 0 <= step.row <= self.n - span):
+                    raise ValueError(
+                        f"step {s} ({step.op}): a key step rewrites rows "
+                        f"[row, row + {span}) of one register in place "
+                        f"from key block >= 0, row a multiple of 8; got "
+                        f"dst={step.dst} a={step.a} key={step.key} "
+                        f"row={step.row} in {self.n} rows")
+
+    @property
+    def key_rows(self) -> int:
+        """Rows of the per-lane key operand a launch must supply: its
+        highest key block read, plus one, times ``KEY_BLOCK``; 0 when no
+        step reads it."""
+        return KEY_BLOCK * (1 + max((s.key for s in self.steps
+                                     if s.op in _KEY_OPS), default=-1))
 
     @property
     def passes(self) -> int:
@@ -309,6 +349,17 @@ class ProgramBuilder:
     def eq_const_at(self, dst: int, a: int, slot: int) -> None:
         self._steps.append(Step(EQ_CONST, dst, a, const=slot))
 
+    def xor_key(self, reg: int, key: int, row: int = 0) -> None:
+        """regs[reg] rows [row, row + 128) ^= key-operand block ``key``."""
+        self._steps.append(Step(XOR_KEY, reg, reg, key=key, row=row))
+
+    def clmul(self, reg: int, key: int, row: int) -> None:
+        """regs[reg] rows [row, row + 256) = the carry-less product of
+        bit 0 of rows [row, row + 128) (coefficient i at row + i) and of
+        key block ``key`` (coefficient j at its row j), lane by lane:
+        coefficient k at row + k, row + 255 zero."""
+        self._steps.append(Step(CLMUL, reg, reg, key=key, row=row))
+
     def build(self, *, rounds: int = 1,
               const_stride: int = 0) -> PlanProgram:
         consts = (np.stack(self._consts).astype(np.int32)
@@ -410,14 +461,15 @@ _OPCODE = {op: i for i, op in enumerate(OPS)}
 
 
 def encode_steps(program: PlanProgram) -> np.ndarray:
-    """One round's step stream as (n_steps, 6) int32 rows — the VM's
-    bytecode: (opcode, dst, a, b, plan, const).  Unused operand fields
-    are clamped to 0 so traced register/table indexing stays in range
-    (the dispatched branch never reads them)."""
+    """One round's step stream as (n_steps, 8) int32 rows — the VM's
+    bytecode: (opcode, dst, a, b, plan, const, key, row).  Unused
+    operand fields are clamped to 0 so traced register/table indexing
+    stays in range (the dispatched branch never reads them)."""
     rows = []
     for s in program.steps:
         rows.append((_OPCODE[s.op], s.dst, s.a, max(s.b, 0),
-                     max(s.plan, 0), max(s.const, 0)))
+                     max(s.plan, 0), max(s.const, 0), max(s.key, 0),
+                     s.row))
     return np.asarray(rows, np.int32)
 
 
@@ -540,9 +592,9 @@ def encode_program(program: PlanProgram) -> tuple:
 
     # The step-stream opcodes index the kernel's op bodies; the two
     # orderings must never drift apart.
-    assert ppk.OPCODES == OPS, (
-        f"kernel opcode table {ppk.OPCODES} drifted from the IR's op "
-        f"order {OPS}")
+    assert ppk.OPCODES == OPS and ppk.KEY_BLOCK == KEY_BLOCK, (
+        f"kernel opcode table {ppk.OPCODES} or key block "
+        f"{ppk.KEY_BLOCK} drifted from the IR's {OPS}, {KEY_BLOCK}")
 
     steps = encode_steps(program)
     n_steps = steps.shape[0]
@@ -595,13 +647,21 @@ def _build_exec(program: PlanProgram, interpret: bool) -> _Exec:
     launch = jax.jit(functools.partial(ppk.plan_program_pallas,
                                        interpret=interpret, **static))
 
-    def run(xp):
-        return launch(xp, *control)
+    def run(xp, keys):
+        return launch(xp, *control, keys=keys)
 
     return _Exec(run, n_pad, dense, walked)
 
 
-def _run_megakernel(program: PlanProgram, x2: Array,
+def lane_pad(d: int) -> int:
+    """Payload lanes rounded up to whole megakernel lane blocks: the
+    width a launch runs at.  A key operand of this width rides as it
+    is; one of the payload's width is padded per launch."""
+    from repro.kernels import plan_program_kernel as ppk  # lazy: kernels opt.
+    return d + (-d) % ppk.LANES
+
+
+def _run_megakernel(program: PlanProgram, x2: Array, keys: Optional[Array],
                     interpret: Optional[bool]) -> Array:
     global _PROGRAM_LAUNCHES, _PASSES_AVOIDED
     from repro.core import telemetry  # lazy: telemetry imports this module
@@ -609,7 +669,7 @@ def _run_megakernel(program: PlanProgram, x2: Array,
     from repro.kernels.ops import default_interpret
     interpret = default_interpret(interpret)
     n, d = x2.shape
-    d_pad = d + (-d) % ppk.LANES
+    d_pad = lane_pad(d)
     key = (id(program), d_pad, str(x2.dtype), bool(interpret))
     hit = _EXEC_CACHE.get(key)
     cache_hit = hit is not None and hit[0] is program
@@ -641,10 +701,15 @@ def _run_megakernel(program: PlanProgram, x2: Array,
     xp = x2
     if (ex.n_pad, d_pad) != (n, d):
         xp = jnp.pad(x2, ((0, ex.n_pad - n), (0, d_pad - d)))
+    if keys is not None:
+        if keys.dtype != x2.dtype:
+            keys = keys.astype(x2.dtype)
+        if keys.shape[1] != d_pad:
+            keys = jnp.pad(keys, ((0, 0), (0, d_pad - keys.shape[1])))
     with _obs.span("program_launch", program=program.name,
                    passes=program.passes, n=n, d=d,
                    exec_cache_hit=cache_hit):
-        return ex.run(xp)[:n, :d]
+        return ex.run(xp, keys)[:n, :d]
 
 
 # ---------------------------------------------------------------------------
@@ -670,8 +735,19 @@ def _apply_pass(plan: xb.PermutePlan, v: Array, pass_backend: str,
     return xb.apply_plan(plan, v, backend=pass_backend, interpret=interpret)
 
 
-def _run_chained(program: PlanProgram, x2: Array, pass_backend: str,
-                 interpret) -> Array:
+def _clmul_host(x: Array, h: Array) -> Array:
+    """(128, D) x (128, D) bit columns -> (256, D): row k is the parity
+    of x[i] & h[j] over i + j = k, lane by lane."""
+    i = np.arange(KEY_BLOCK)
+    terms = (x & 1)[:, None, :] * (h & 1)[None, :, :]
+    prod = jnp.zeros((2 * KEY_BLOCK, x.shape[1]), jnp.int32).at[
+        (i[:, None] + i[None, :]).reshape(-1)].add(
+            terms.reshape(-1, x.shape[1]).astype(jnp.int32))
+    return prod & 1
+
+
+def _run_chained(program: PlanProgram, x2: Array, keys: Optional[Array],
+                 pass_backend: str, interpret) -> Array:
     regs = [x2] + [jnp.zeros_like(x2)
                    for _ in range(program.n_regs - 1)]
     consts = (None if program.consts is None
@@ -696,6 +772,12 @@ def _run_chained(program: PlanProgram, x2: Array, pass_backend: str,
             elif step.op == EQ_CONST:
                 val = (a == consts[step.const + off].astype(a.dtype)[:, None]
                        ).astype(a.dtype)
+            elif step.op in _KEY_OPS:
+                block = keys[KEY_BLOCK * step.key:KEY_BLOCK * (step.key + 1)]
+                win = a[step.row:step.row + KEY_BLOCK]
+                val = (win ^ block if step.op == XOR_KEY
+                       else _clmul_host(win, block)).astype(a.dtype)
+                val = a.at[step.row:step.row + val.shape[0]].set(val)
             else:  # XOR_CONST
                 val = a ^ consts[step.const + off].astype(a.dtype)[:, None]
             regs[step.dst] = val
@@ -710,6 +792,7 @@ def run_program(
     program: PlanProgram,
     x: Array,
     *,
+    keys: Optional[Array] = None,
     backend: str = "megakernel",
     pass_backend: str = "einsum",
     interpret: Optional[bool] = None,
@@ -717,6 +800,10 @@ def run_program(
     """Execute a plan program over an ``(n,)`` or ``(n, D)`` payload.
 
     Args:
+      keys: the per-lane key operand, ``(program.key_rows, D)`` integer
+        bits, or already ``lane_pad(D)`` lanes wide (lanes past D are
+        not read), for a program whose key steps read it; None
+        otherwise.
       backend: 'megakernel' (one VMEM-resident Pallas launch) or
         'chained' (one ``apply_plan`` per PERMUTE step with XLA
         elementwise between — the reference lowering and the
@@ -740,10 +827,25 @@ def run_program(
         raise ValueError(
             "ROTLV needs an unsigned payload (logical right shift); got "
             f"{x2.dtype} — bitcast ARX states to uint32 first")
+    if program.key_rows:
+        keys = jnp.asarray(keys) if keys is not None else None
+        lanes = (x2.shape[1], lane_pad(x2.shape[1]))
+        if (keys is None or keys.ndim != 2
+                or keys.shape[0] != program.key_rows
+                or keys.shape[1] not in lanes
+                or not jnp.issubdtype(keys.dtype, jnp.integer)):
+            raise ValueError(
+                f"program {program.name!r} reads a ({program.key_rows}, "
+                f"{' or '.join(map(str, lanes))}) integer key operand, "
+                f"got {None if keys is None else (keys.shape, keys.dtype)}")
+    elif keys is not None:
+        raise ValueError(f"program {program.name!r} reads no key operand")
     if backend == "megakernel":
-        out2 = _run_megakernel(program, x2, interpret)
+        out2 = _run_megakernel(program, x2, keys, interpret)
     elif backend == "chained":
-        out2 = _run_chained(program, x2, pass_backend, interpret)
+        out2 = _run_chained(program, x2,
+                            None if keys is None else keys[:, :x2.shape[1]],
+                            pass_backend, interpret)
     else:
         raise ValueError(f"unknown program backend {backend!r}")
     return out2[:, 0] if single else out2

@@ -105,13 +105,16 @@ def program_step_fingerprint(program: "pp.PlanProgram", step) -> tuple:
     PERMUTE steps carry their plan's full ``schedule_fingerprint`` (so
     a re-tiled or re-weighted plan is a different step even at the same
     slot); arithmetic steps are identified by opcode, register wiring,
-    and constant-row slot (row *contents* enter the program fingerprint
-    through the constants-table digest, which also covers the strided
-    rows a per-round constant walks).
+    key block and row window (key steps), and constant-row slot (row
+    *contents* enter the program fingerprint through the constants-table
+    digest, which also covers the strided rows a per-round constant
+    walks).
     """
     if step.op == "permute":
         return (step.op, step.dst, step.a,
                 schedule_fingerprint(program.plans[step.plan]))
+    if step.key >= 0:
+        return (step.op, step.dst, step.a, step.key, step.row)
     if step.const >= 0:
         return (step.op, step.dst, step.a, step.const)
     return (step.op, step.dst, step.a, step.b)

@@ -19,12 +19,18 @@ Two lowerings share the math:
   crossbar backends and is the CAVP differential reference.
 
 * **Fused program** (``backend='fused'``): one ``PlanProgram`` per
-  (key, record geometry) executes the *whole* seal — CTR keystream for
-  every block, ciphertext XOR, GHASH absorb, and the final tag — in a
-  single megakernel launch for a whole batch of records.  The program
-  state is a bit matrix: payload lanes are records, rows are
+  record geometry, whatever the keys, executes the *whole* seal — CTR
+  keystream for every block, ciphertext XOR, GHASH absorb, and the
+  final tag — in a single megakernel launch for a whole batch of
+  records, each lane under its own key.  The program state is a bit
+  matrix: payload lanes are records, rows are
 
-  ``[stream | Y | E(J0) | IV | LEN | AAD | one-hot scratch]``
+  ``[stream | E(J0) | IV | LEN | AAD | Y window | one-hot scratch]``
+
+  and each lane's key material rides beside it as the launch's key
+  operand (``KEY_ROWS`` rows: the 11 round keys and H = E_K(0), from
+  ``key_material``), so the program's steps, select entries and dense
+  tables are the same for every key.
 
   - AES runs on 128 bit rows per block with the S-box factored through
     *nibble* one-hots so the lookup never needs a 128-select parity: a
@@ -36,28 +42,29 @@ Two lowerings share the math:
     k=16 fold reads S(v)'s bits back out — 37 gather columns per round
     where the byte-wide one-hot decode needed 136.  The per-round
     linear layer is ``lift_gf2_k(ShiftRows∘MixColumns)``,
-    select-compacted (32 slots -> ~7).
+    select-compacted (32 slots -> ~7).  AddRoundKey is ``XOR_KEY``
+    with the lane's round key.
   - Counter blocks never ride as input: each trip re-routes the
     record's IV bits and XORs a *per-trip constant* row carrying the
-    32-bit block counter and the whitening key — counter agility as
-    control information, exactly like the key schedule.
-  - The GHASH accumulator Y lives in the stream register and absorbs
-    via Horner: ONE PERMUTE per trip both shifts the plaintext stream,
-    appends the new ciphertext block, keeps E(J0), and computes
-    (Y ^ C_t)·H — the multiply-by-H bit matrix reads the Y rows and
-    the C rows with the same select pattern, so the XOR and the field
-    multiply are one fused gather.
+    32-bit block counter — counter agility as control information.
+  - GHASH multiplies by the lane's H with ``CLMUL``: the 128-row
+    factor, in coefficient order at the head of the Y window, times key
+    block ``H_BLOCK``, unreduced, fills the 256-row window.  Its fixed
+    reduction mod ``GCM_POLY`` is select lists that the next gather
+    reads, so ONE PERMUTE per trip shifts the plaintext stream, appends
+    the new ciphertext block, keeps E(J0), and forms the next factor
+    reduce(Y) ^ C_t, followed by one ``CLMUL``.
   - Partial final blocks mask their dead bit rows in the absorb plan's
     control (the keystream tail must not leak into the tag), so
     non-multiple-of-16 records are exact without any data-dependent
     branch.
 
-  Trip 0 encrypts J0 itself (the tag mask); the epilogue XORs the
-  length block into Y (the LEN bits are pre-routed to Y's rows), runs
-  the final multiply, and lands ``[ciphertext bits | tag bits]`` in
-  register 0.  Launches and avoided passes feed the telemetry ledger;
-  ``fixed_latency=True`` asserts 1 launch / 0 crossbar passes under
-  the registry's program fingerprint.
+  Trip 0 encrypts J0 itself (the tag mask); the AAD blocks are
+  absorbed by the same factor-and-``CLMUL`` pair before it; the
+  epilogue forms reduce(Y) ^ LEN, runs the final multiply, and lands
+  ``[ciphertext bits | tag bits]`` in register 0.  Launches and avoided
+  passes feed the telemetry ledger; ``fixed_latency=True`` asserts 1
+  launch / 0 crossbar passes under the registry's program fingerprint.
 
 Only 96-bit IVs are supported (J0 = IV || 0^31 || 1 — the NIST
 SP 800-38D fast path and the CAVP coverage target); other IV lengths
@@ -99,6 +106,15 @@ GCM_POLY = (1 << 128) | 0x87
 
 _REV8 = np.array([int(f"{i:08b}"[::-1], 2) for i in range(256)], np.int32)
 
+# Per-lane key material: key blocks 0..10 are the AES round keys in the
+# state's bit order (row 8j + b = bit b of byte j), block H_BLOCK is
+# H = E_K(0^128) in field-coefficient order (row e = coefficient x^e).
+# Packed, one session is KEY_BYTES: the 176 round-key bytes, then H's
+# 16 bytes as REV8 maps them.
+H_BLOCK = aes_mod.ROUNDS + 1
+KEY_ROWS = pp.KEY_BLOCK * (H_BLOCK + 1)
+KEY_BYTES = KEY_ROWS // 8
+
 
 class InvalidTagError(Exception):
     """Authentication failed for at least one record (``.indices`` says
@@ -139,49 +155,17 @@ def _hpowers(h: int, n: int) -> List[int]:
     return out
 
 
-_MUL_BITS_CACHE: dict = {}
-
-
-def _mul_bits(factor: int) -> np.ndarray:
-    """(128, 128) uint8 bit matrix of multiply-by-``factor``, in BLOCK
-    row order (row 8j+b = value-bit b of byte j, the lift's LSB-first
-    convention): out = M @ in over GF(2).
-
-    The per-byte bit reflection between block order and field order is
-    conjugated in here once, so the program's GHASH rows never need a
-    separate swap pass.
-    """
-    m = _MUL_BITS_CACHE.get(factor)
-    if m is not None:
-        return m
-    m = np.zeros((128, 128), np.uint8)
-    for r_in in range(128):
-        jbyte, bval = r_in >> 3, r_in & 7
-        e_in = 8 * jbyte + (7 - bval)
-        prod = sr.gf2k_mul_int(factor, 1 << e_in, 128, GCM_POLY)
-        while prod:
-            e = prod.bit_length() - 1
-            m[8 * (e >> 3) + (7 - (e & 7)), r_in] = 1
-            prod ^= 1 << e
-    _MUL_BITS_CACHE[factor] = m
-    return m
-
-
-def _key_digest(key: bytes) -> str:
-    import hashlib
-    return hashlib.sha256(b"gcm-key:" + key).hexdigest()[:12]
-
-
 # ---------------------------------------------------------------------------
-# Host AES (control information: H = E_K(0), key schedule already host-side)
+# Host AES (key material: the round keys and H = E_K(0), as a handshake
+# installs them)
 # ---------------------------------------------------------------------------
 
 def _host_encrypt_block(rks: np.ndarray, block: bytes) -> bytes:
-    """Pure-NumPy AES-128 block encryption.
+    """Pure-NumPy AES-128 block encryption of one block.
 
-    H and the J0-free program constants are *control* information
-    (functions of the key alone), so they are computed host-side like
-    the key schedule itself — never through the device data path.
+    H is a function of the key alone, so it is computed host-side like
+    the key schedule itself: here for the chained lowering's GHASH
+    weights, and in ``key_material`` for many keys at once.
     """
     sbox, _ = aes_mod.sbox_tables()
     st = np.frombuffer(block, np.uint8).astype(np.int32) ^ rks[0]
@@ -210,6 +194,59 @@ def _hash_key(key: bytes) -> int:
     """H = E_K(0^128) as a reflected field integer."""
     rks = aes_mod.key_expansion(key)
     return _block_to_field(_host_encrypt_block(rks, b"\x00" * BLOCK))
+
+
+def _xtime(v: np.ndarray) -> np.ndarray:
+    return ((v << 1) ^ np.where(v & 0x80, 0x1B, 0)).astype(np.uint8)
+
+
+def key_material(keys: Sequence[bytes]) -> np.ndarray:
+    """(S, KEY_BYTES) uint8: each AES-128 key expanded as a handshake
+    installs it — the 11 round keys (FIPS 197 5.2) and H = E_K(0^128) —
+    all keys at once, on the host."""
+    k = np.frombuffer(b"".join(keys), np.uint8).reshape(-1, BLOCK)
+    if k.shape[0] != len(keys) or any(len(x) != BLOCK for x in keys):
+        raise ValueError("AES-128 keys must be 16 bytes each")
+    sbox = aes_mod.sbox_tables()[0].astype(np.uint8)
+    w = [k[:, 4 * i:4 * i + 4] for i in range(4)]
+    rcon = np.uint8(1)
+    for i in range(4, 4 * (aes_mod.ROUNDS + 1)):
+        t = w[i - 1]
+        if i % 4 == 0:
+            t = sbox[np.roll(t, -1, axis=1)]
+            t[:, 0] ^= rcon
+            rcon = _xtime(np.array(rcon))
+        w.append(w[i - 4] ^ t)
+    rks = np.stack([np.concatenate(w[4 * r:4 * r + 4], axis=1)
+                    for r in range(aes_mod.ROUNDS + 1)], axis=1)
+    # H = E_K(0): columns c of the state are bytes 4c..4c+3.
+    st = rks[:, 0].copy()
+    c, r = np.meshgrid(np.arange(4), np.arange(4), indexing="ij")
+    for rnd in range(1, aes_mod.ROUNDS + 1):
+        sq = sbox[st].reshape(-1, 4, 4)[:, (c + r) % 4, r]
+        if rnd < aes_mod.ROUNDS:
+            t = sq[:, :, 0] ^ sq[:, :, 1] ^ sq[:, :, 2] ^ sq[:, :, 3]
+            sq = sq ^ t[:, :, None] ^ _xtime(sq ^ np.roll(sq, -1, axis=2))
+        st = sq.reshape(-1, BLOCK) ^ rks[:, rnd]
+    return np.concatenate([rks.reshape(-1, BLOCK * (aes_mod.ROUNDS + 1)),
+                           _REV8[st].astype(np.uint8)], axis=1)
+
+
+def key_lanes(keys: Sequence[bytes]) -> np.ndarray:
+    """(KEY_ROWS, S) int32 bits: the key operand of S lanes, lane i
+    keyed by ``keys[i]``."""
+    return np.unpackbits(key_material(keys), axis=1,
+                         bitorder="little").T.astype(np.int32)
+
+
+@jax.jit
+def session_keys(table: Array, idx: Array) -> Array:
+    """The key operand of ``len(idx)`` lanes gathered from a device
+    session table (``key_material`` rows): lane i reads session
+    ``idx[i]``, unpacked to (KEY_ROWS, len(idx)) int32 bits."""
+    rows = table[idx]
+    bits = (rows[:, :, None] >> jnp.arange(8, dtype=rows.dtype)) & 1
+    return bits.reshape(idx.shape[0], KEY_ROWS).T.astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -307,10 +344,13 @@ def _geometry(pt_len: int, aad_len: int) -> Tuple[int, int, int]:
 
 
 def _layout(m: int, a: int) -> dict:
-    lay = {"stream": 0, "y": 128 * m, "ej0": 128 * m + 128,
-           "iv": 128 * m + 256, "len": 128 * m + 352,
-           "aad": 128 * m + 480, "onehot": 128}
-    n = max(128 + ONEHOT_ROWS + PRODUCT_ROWS, lay["aad"] + 128 * a)
+    """Row offsets of the state's regions; ``y`` is GHASH's 256-row
+    window: the unreduced product of the last multiply by H, whose first
+    128 rows are the next factor while it is formed."""
+    lay = {"stream": 0, "ej0": 128 * m, "iv": 128 * m + 128,
+           "len": 128 * m + 224, "aad": 128 * m + 352,
+           "y": 128 * m + 352 + 128 * a, "onehot": 128}
+    n = max(128 + ONEHOT_ROWS + PRODUCT_ROWS, lay["y"] + 256)
     lay["n"] = n + (-n) % 8
     return lay
 
@@ -423,10 +463,9 @@ def _live_bits(last: int) -> List[int]:
     return [8 * j + b for j in range(last) for b in range(8)]
 
 
-def _emit_aes_rounds(b: pp.ProgramBuilder, plans: dict,
-                     rk_rows: np.ndarray) -> None:
-    """SubBytes/linear/AddRoundKey for rounds 1..10 on register 0 (the
-    whitening XOR is fused into the caller's counter constant)."""
+def _emit_aes_rounds(b: pp.ProgramBuilder, plans: dict) -> None:
+    """SubBytes/linear/AddRoundKey for rounds 1..10 on register 0, the
+    round keys read from the lane's key material (key blocks 1..10)."""
     for rnd in range(1, aes_mod.ROUNDS + 1):
         b.permute(1, 0, plans["nspread"])
         b.eq_const(1, 1, plans["u_row"])
@@ -436,68 +475,102 @@ def _emit_aes_rounds(b: pp.ProgramBuilder, plans: dict,
         b.permute(0, 1, plans["nfold"])     # S(v) bits, full overwrite
         b.permute(0, 0,
                   plans["linear" if rnd < aes_mod.ROUNDS else "sr_bits"])
-        b.xor_const(0, 0, rk_rows[rnd])
+        b.xor_key(0, rnd)
 
 
-def build_gcm_program(key: bytes, pt_len: int, aad_len: int, *,
+def _coeff_row(e: int) -> int:
+    """Block bit row of field coefficient ``e`` (and back: an
+    involution): coefficient 8j + k is bit 7 - k of byte j."""
+    return 8 * (e >> 3) + 7 - (e & 7)
+
+
+def _reduction() -> List[List[int]]:
+    """Per coefficient c < 128: the product coefficients k < 255 whose
+    x^k mod GCM_POLY has coefficient c — the fixed reduction of CLMUL's
+    output, as select lists."""
+    rows: List[List[int]] = [[] for _ in range(128)]
+    for k in range(255):
+        v = 1 << k
+        for e in range(254, 127, -1):
+            if (v >> e) & 1:
+                v ^= GCM_POLY << (e - 128)
+        for c in range(128):
+            if (v >> c) & 1:
+                rows[c].append(k)
+    return rows
+
+
+def build_gcm_program(pt_len: int, aad_len: int, *,
                       open_mode: bool = False) -> Tuple[pp.PlanProgram,
                                                         dict]:
-    """The one-launch seal/open schedule for one (key, record geometry).
+    """The one-launch seal/open schedule for one record geometry.
 
     Returns (program, layout).  The program maps an ``(n, B)`` 0/1 bit
-    state (B records as payload lanes, packed by ``_pack_records``) to
-    ``[ciphertext|plaintext bits, tag bits]`` in register 0.
+    state (B records as payload lanes, packed by ``_pack_records``) and
+    the lanes' key material (``KEY_ROWS`` rows, ``lane_keys``) to
+    ``[ciphertext|plaintext bits, tag bits]`` in register 0.  No step
+    depends on a key: AddRoundKey XORs key blocks 0..10, and every
+    multiply by H is a CLMUL with key block 11 followed by a reduction
+    the next gather folds in.
     """
     m, last, a = _geometry(pt_len, aad_len)
     lay = _layout(m, a)
     n = lay["n"]
-    h = _hash_key(key)
-    rks = aes_mod.key_expansion(key)
+    y = lay["y"]
     plans = _aes_bit_plans(n)
+    red = _reduction()
 
-    rk_rows = np.zeros((aes_mod.ROUNDS + 1, n), np.int32)
-    for r in range(aes_mod.ROUNDS + 1):
-        rk_rows[r, :128] = _bits_row(rks[r])
+    def factor(rows: List[List[int]], src: int, dead=()) -> None:
+        """Y's factor rows (coefficient order) <- the reduced product in
+        the window ^ the block at ``src`` (block order, ``dead`` bit
+        rows dropped)."""
+        for c in range(128):
+            r = _coeff_row(c)
+            rows[y + c] = [y + k for k in red[c]] + (
+                [] if r in dead else [src + r])
 
-    mulh = _mul_bits(h)
-    hpow = _hpowers(h, max(a, 1))
+    def keep(rows: List[List[int]], base: int, count: int) -> None:
+        for i in range(base, base + count):
+            rows[i] = [i]
 
-    # d1: stream <- plaintext/ciphertext rows; Y <- AAD Horner seed
-    # Sum_j A_j H^(a-j+1) (each trip and the epilogue multiply by H once
-    # more, landing A_j at H^(M+1-j) exactly).
+    # d1: stream <- plaintext/ciphertext rows, the later AAD blocks in
+    # place, and the first AAD block as the first factor of Y.
     rows: List[List[int]] = [[] for _ in range(n)]
-    for i in range(128 * m):
-        rows[lay["stream"] + i] = [i]
-    for j in range(1, a + 1):
-        mj = _mul_bits(hpow[a - j])              # H^(a-j+1)
-        base = lay["aad"] + 128 * (j - 1)
-        for i in range(128):
-            rows[lay["y"] + i].extend(base + int(c)
-                                      for c in np.nonzero(mj[i])[0])
+    keep(rows, lay["stream"], 128 * m)
+    keep(rows, lay["aad"] + 128, 128 * (a - 1))
+    for c in range(128 if a else 0):
+        rows[y + c] = [lay["aad"] + _coeff_row(c)]
     d1 = _ragged_gather(rows, n)
 
-    # d2: keep IV in place; route LEN onto Y's rows so the epilogue's
-    # whole-register XOR lands Y ^ LEN with no extra pass.
+    def fold_aad(j: int) -> xb.PermutePlan:
+        """Y's factor <- (Y so far) ^ AAD block j (1-based), keeping the
+        stream and the blocks after j."""
+        rows = [[] for _ in range(n)]
+        keep(rows, lay["stream"], 128 * m)
+        keep(rows, lay["aad"] + 128 * j, 128 * (a - j))
+        factor(rows, lay["aad"] + 128 * (j - 1))
+        return _ragged_gather(rows, n)
+
+    # d2: keep IV and LEN in register 3.
     rows = [[] for _ in range(n)]
-    for i in range(96):
-        rows[lay["iv"] + i] = [lay["iv"] + i]
-    for i in range(128):
-        rows[lay["y"] + i] = [lay["len"] + i]
+    keep(rows, lay["iv"], 96)
+    keep(rows, lay["len"], 128)
     d2 = _ragged_gather(rows, n)
 
     # Per-trip counter load: IV bits to rows 0..95 (the 32-bit counter
-    # and the whitening key arrive as the trip's constant row).
+    # arrives as the trip's constant row, the whitening key as key
+    # block 0).
     rows = [[] for _ in range(n)]
     for i in range(96):
         rows[i] = [lay["iv"] + i]
     ctr = _ragged_gather(rows, n)
 
     def ctr_const(t: int) -> np.ndarray:
-        row = rk_rows[0].copy()
+        row = np.zeros(n, np.int32)
         ctr_bytes = np.zeros(BLOCK, np.int32)
         ctr_bytes[12:] = np.frombuffer(int(t + 1).to_bytes(4, "big"),
                                        np.uint8)
-        row[:128] ^= _bits_row(ctr_bytes)
+        row[:128] = _bits_row(ctr_bytes)
         return row
 
     # Trip 0 epilogue: park E_K(J0) (the tag mask) in its stream rows.
@@ -506,26 +579,20 @@ def build_gcm_program(key: bytes, pt_len: int, aad_len: int, *,
         rows[lay["ej0"] + i] = [i]
     place_ej0 = _ragged_gather(rows, n)
 
-    def absorb_plan(src_c: int, dead: List[int],
-                    masked_tail: bool) -> xb.PermutePlan:
-        """shift stream + append C + keep E(J0) + Y <- (Y ^ C_t)·H, all
-        one gather.  ``src_c`` is where C's bit rows sit in the source
-        register; ``dead`` C rows are dropped from absorb and append
-        (partial final block)."""
+    def absorb_plan(dead: List[int]) -> xb.PermutePlan:
+        """shift stream + append C + keep E(J0) + Y's factor <- Y ^ C_t,
+        all one gather, C_t in the stream's front block; ``dead`` C rows
+        are dropped from absorb and append (partial final block)."""
         dead_set = set(dead)
         rows = [[] for _ in range(n)]
         for i in range(128 * (m - 1)):
             rows[lay["stream"] + i] = [lay["stream"] + 128 + i]
         for r in range(128):
-            if not (masked_tail and r in dead_set):
-                rows[lay["stream"] + 128 * (m - 1) + r] = [src_c + r]
-        for i in range(128):
-            sel = [lay["y"] + int(c) for c in np.nonzero(mulh[i])[0]]
-            sel += [src_c + int(c) for c in np.nonzero(mulh[i])[0]
-                    if int(c) not in dead_set]
-            rows[lay["y"] + i] = sel
-        for i in range(128):
-            rows[lay["ej0"] + i] = [lay["ej0"] + i]
+            if r not in dead_set:
+                rows[lay["stream"] + 128 * (m - 1) + r] = [
+                    lay["stream"] + r]
+        keep(rows, lay["ej0"], 128)
+        factor(rows, lay["stream"], dead_set)
         return _ragged_gather(rows, n)
 
     def route_ks(dead: List[int]) -> xb.PermutePlan:
@@ -538,14 +605,18 @@ def build_gcm_program(key: bytes, pt_len: int, aad_len: int, *,
                 rows[lay["stream"] + 128 * (m - 1) + r] = [r]
         return _ragged_gather(rows, n)
 
-    # Epilogue output: ciphertext stream + tag = (Y ^ LEN)·H ^ E(J0).
+    # Epilogue: Y's factor <- Y ^ LEN, then the output: ciphertext
+    # stream + tag = reduced (Y ^ LEN)·H ^ E(J0), in block order.
     rows = [[] for _ in range(n)]
-    for i in range(128 * m):
-        rows[lay["stream"] + i] = [lay["stream"] + i]
-    for i in range(128):
-        rows[128 * m + i] = ([lay["y"] + int(c)
-                              for c in np.nonzero(mulh[i])[0]]
-                             + [lay["ej0"] + i])
+    keep(rows, lay["stream"], 128 * m)
+    keep(rows, lay["ej0"], 128)
+    factor(rows, lay["len"])
+    fin = _ragged_gather(rows, n)
+    rows = [[] for _ in range(n)]
+    keep(rows, lay["stream"], 128 * m)
+    for r in range(128):
+        rows[128 * m + r] = ([y + k for k in red[_coeff_row(r)]]
+                             + [lay["ej0"] + r])
     e2 = _ragged_gather(rows, n)
 
     dead_last = ([r for r in range(128) if r not in set(_live_bits(last))]
@@ -555,10 +626,15 @@ def build_gcm_program(key: bytes, pt_len: int, aad_len: int, *,
         f"gcm_{'open' if open_mode else 'seal'}_m{m}", n, n_regs=4)
     b.permute(2, 0, d1)
     b.permute(3, 0, d2)
+    for j in range(1, a + 1):               # Y = (..(A_1·H ^ A_2)·H ..)·H
+        if j > 1:
+            b.permute(2, 2, fold_aad(j))
+        b.clmul(2, H_BLOCK, y)
     for t in range(m + 1):
         b.permute(0, 3, ctr)
         b.xor_const(0, 0, ctr_const(t))
-        _emit_aes_rounds(b, plans, rk_rows)
+        b.xor_key(0, 0)
+        _emit_aes_rounds(b, plans)
         if t == 0:
             b.permute(1, 0, place_ej0)
             b.xor(2, 2, 1)
@@ -566,54 +642,54 @@ def build_gcm_program(key: bytes, pt_len: int, aad_len: int, *,
             dead = dead_last if t == m else []
             if not open_mode:
                 b.xor(1, 0, 2)      # rows 0..127: C_t = ks ^ pt front
-                b.permute(2, 1, absorb_plan(lay["stream"], dead,
-                                            masked_tail=t == m))
+                b.permute(2, 1, absorb_plan(dead))
+                b.clmul(2, H_BLOCK, y)
             else:
                 # Absorb the received C_t straight from the stream, then
                 # overlay the keystream on the appended copy -> PT_t.
-                b.permute(1, 2, absorb_plan(lay["stream"], [],
-                                            masked_tail=False))
+                b.permute(1, 2, absorb_plan([]))
+                b.clmul(1, H_BLOCK, y)
                 b.permute(0, 0, route_ks(dead))
                 b.xor(2, 1, 0)
     b.xor(1, 2, 3)
+    b.permute(1, 1, fin)
+    b.clmul(1, H_BLOCK, y)
     b.permute(0, 1, e2)
     return b.build(), lay
 
 
-def seal_device_fn(key: bytes, pt_len: int, aad_len: int, *,
-                   open_mode: bool = False):
-    """(fn, layout) where ``fn(bits)`` is the COMPLETE device portion of
-    a fused seal/open — one program launch from packed record bits to
-    ciphertext+tag bits.  This is the region
-    ``REGISTRY.audit_constant_time`` abstract-evaluates: everything
-    outside it is host marshalling of data the schedule never reads.
+def seal_device_fn(pt_len: int, aad_len: int, *, open_mode: bool = False):
+    """(fn, layout) where ``fn(bits, keys)`` is the COMPLETE device
+    portion of a fused seal/open — one program launch from packed record
+    bits and the lanes' key material to ciphertext+tag bits.  This is
+    the region ``REGISTRY.audit_constant_time`` abstract-evaluates:
+    everything outside it is host marshalling of data the schedule never
+    reads.
     """
-    _, program, lay = gcm_program(key, pt_len, aad_len,
-                                  open_mode=open_mode)
+    _, program, lay = gcm_program(pt_len, aad_len, open_mode=open_mode)
 
-    def fn(bts: Array) -> Array:
-        return pp.run_program(program, bts, backend="megakernel")
+    def fn(bts: Array, keys: Array) -> Array:
+        return pp.run_program(program, bts, keys=keys, backend="megakernel")
 
     return fn, lay
 
 
-def _program_key(key: bytes, pt_len: int, aad_len: int,
-                 open_mode: bool) -> str:
+def _program_key(pt_len: int, aad_len: int, open_mode: bool) -> str:
     m, last, a = _geometry(pt_len, aad_len)
     mode = "open" if open_mode else "seal"
-    return f"gcm/aes128/{_key_digest(key)}/{mode}/m{m}.{last}a{a}"
+    return f"gcm/aes128/{mode}/m{m}.{last}a{a}"
 
 
-def gcm_program(key: bytes, pt_len: int, aad_len: int, *,
+def gcm_program(pt_len: int, aad_len: int, *,
                 open_mode: bool = False) -> Tuple[str, pp.PlanProgram,
                                                   dict]:
-    """Registry-cached fused program for one (key, geometry); returns
-    (registry key, program, row layout)."""
-    prog_key = _program_key(key, pt_len, aad_len, open_mode)
+    """Registry-cached fused program for one record geometry, whatever
+    the keys; returns (registry key, program, row layout)."""
+    prog_key = _program_key(pt_len, aad_len, open_mode)
     holder: dict = {}
 
     def build():
-        program, lay = build_gcm_program(key, pt_len, aad_len,
+        program, lay = build_gcm_program(pt_len, aad_len,
                                          open_mode=open_mode)
         holder["lay"] = lay
         return program
@@ -707,11 +783,11 @@ def no_phase(name: Optional[str] = None) -> None:
     """
 
 
-def _run_fused(key: bytes, ivs, records, aads, pt_len: int, aad_len: int,
+def _run_fused(lanes, ivs, records, aads, pt_len: int, aad_len: int,
                *, open_mode: bool, fixed_latency: bool,
                interpret: Optional[bool], phase=no_phase):
     m, _, a = _geometry(pt_len, aad_len)
-    prog_key, program, lay = gcm_program(key, pt_len, aad_len,
+    prog_key, program, lay = gcm_program(pt_len, aad_len,
                                          open_mode=open_mode)
     bits = _pack_records(lay, ivs, records, aads, pt_len, aad_len)
     phase("launch")
@@ -723,7 +799,8 @@ def _run_fused(key: bytes, ivs, records, aads, pt_len: int, aad_len: int,
     def run():
         with _obs.span(op, records=len(ivs), blocks=m, aad_blocks=a,
                        program=prog_key):
-            return pp.run_program(program, bts, backend="megakernel",
+            return pp.run_program(program, bts, keys=lanes,
+                                  backend="megakernel",
                                   interpret=interpret)
 
     if fixed_latency:
@@ -778,7 +855,32 @@ def _seal_chained_core(key: bytes, iv: bytes, data: bytes, aad: bytes, *,
 # Public API
 # ---------------------------------------------------------------------------
 
-def aes128_gcm_seal_batch(key: bytes, ivs: Sequence[bytes],
+def _record_keys(key, n: int) -> list:
+    """One key for the batch, or one per record, as a list of n keys."""
+    keys = [key] * n if isinstance(key, (bytes, bytearray)) else list(key)
+    if len(keys) != n:
+        raise ValueError(f"{len(keys)} keys for {n} records")
+    return keys
+
+
+def seal_lanes(lanes, ivs: Sequence[bytes], plaintexts: Sequence[bytes],
+               aads: Optional[Sequence[bytes]] = None, *,
+               fixed_latency: bool = False,
+               interpret: Optional[bool] = None,
+               phase=no_phase) -> List[bytes]:
+    """The fused seal of B same-geometry records whose key material is
+    already the key operand ``lanes`` ((KEY_ROWS, B) or padded to
+    ``pp.lane_pad(B)`` lanes, lane i keying record i: ``key_lanes`` or
+    ``session_keys``); one program launch."""
+    aads = _check_batch(ivs, plaintexts, aads)
+    bodies, tags = _run_fused(lanes, ivs, plaintexts, aads,
+                              len(plaintexts[0]), len(aads[0]),
+                              open_mode=False, fixed_latency=fixed_latency,
+                              interpret=interpret, phase=phase)
+    return [c + t for c, t in zip(bodies, tags)]
+
+
+def aes128_gcm_seal_batch(key, ivs: Sequence[bytes],
                           plaintexts: Sequence[bytes],
                           aads: Optional[Sequence[bytes]] = None, *,
                           backend: str = "fused",
@@ -787,53 +889,55 @@ def aes128_gcm_seal_batch(key: bytes, ivs: Sequence[bytes],
                           phase=no_phase) -> List[bytes]:
     """Seal B same-geometry records; returns ``ciphertext || tag`` each.
 
-    backend='fused' runs the whole batch as ONE plan-program launch;
-    any crossbar backend name runs the chained per-block lowering
-    per record (the CAVP reference path), which launches and syncs per
-    block and so reads as one ``launch`` stage to ``phase``
-    (``no_phase`` says what it is told).
+    ``key`` is one 16-byte key for the batch or a sequence of one per
+    record.  backend='fused' runs the whole batch as ONE plan-program
+    launch, the keys expanded on the host into its key operand; any
+    crossbar backend name runs the chained per-block lowering per record
+    (the CAVP reference path), which launches and syncs per block and so
+    reads as one ``launch`` stage to ``phase`` (``no_phase`` says what
+    it is told).
     """
     aads = _check_batch(ivs, plaintexts, aads)
-    pt_len, aad_len = len(plaintexts[0]), len(aads[0])
+    keys = _record_keys(key, len(ivs))
     if backend == "fused":
-        bodies, tags = _run_fused(key, ivs, plaintexts, aads, pt_len,
-                                  aad_len, open_mode=False,
-                                  fixed_latency=fixed_latency,
-                                  interpret=interpret, phase=phase)
-        return [c + t for c, t in zip(bodies, tags)]
+        return seal_lanes(key_lanes(keys), ivs, plaintexts, aads,
+                          fixed_latency=fixed_latency, interpret=interpret,
+                          phase=phase)
     phase("launch")
     out = []
-    for iv, pt, aad in zip(ivs, plaintexts, aads):
-        c, t = _seal_chained_core(key, iv, pt, aad, open_mode=False,
+    for k, iv, pt, aad in zip(keys, ivs, plaintexts, aads):
+        c, t = _seal_chained_core(k, iv, pt, aad, open_mode=False,
                                   backend=backend, interpret=interpret)
         out.append(c + t)
     return out
 
 
-def aes128_gcm_open_batch(key: bytes, ivs: Sequence[bytes],
+def aes128_gcm_open_batch(key, ivs: Sequence[bytes],
                           ciphertexts: Sequence[bytes],
                           aads: Optional[Sequence[bytes]] = None, *,
                           backend: str = "fused",
                           fixed_latency: bool = False,
                           interpret: Optional[bool] = None) -> List[bytes]:
-    """Open B sealed records (``ciphertext || tag`` each); raises
-    ``InvalidTagError`` (with the failing indices) unless every tag
-    verifies — no plaintext escapes a failed batch."""
+    """Open B sealed records (``ciphertext || tag`` each, ``key`` one
+    key or one per record); raises ``InvalidTagError`` (with the failing
+    indices) unless every tag verifies — no plaintext escapes a failed
+    batch."""
     aads = _check_batch(ivs, ciphertexts, aads)
+    keys = _record_keys(key, len(ivs))
     if any(len(c) < TAG_BYTES for c in ciphertexts):
         raise ValueError("sealed record shorter than the 16-byte tag")
     bodies_in = [c[:-TAG_BYTES] for c in ciphertexts]
     tags_in = [c[-TAG_BYTES:] for c in ciphertexts]
     pt_len, aad_len = len(bodies_in[0]), len(aads[0])
     if backend == "fused":
-        bodies, tags = _run_fused(key, ivs, bodies_in, aads, pt_len,
-                                  aad_len, open_mode=True,
+        bodies, tags = _run_fused(key_lanes(keys), ivs, bodies_in, aads,
+                                  pt_len, aad_len, open_mode=True,
                                   fixed_latency=fixed_latency,
                                   interpret=interpret)
     else:
         bodies, tags = [], []
-        for iv, ct, aad in zip(ivs, bodies_in, aads):
-            b_, t_ = _seal_chained_core(key, iv, ct, aad, open_mode=True,
+        for k, iv, ct, aad in zip(keys, ivs, bodies_in, aads):
+            b_, t_ = _seal_chained_core(k, iv, ct, aad, open_mode=True,
                                         backend=backend,
                                         interpret=interpret)
             bodies.append(b_)

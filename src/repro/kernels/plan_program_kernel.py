@@ -39,7 +39,16 @@ the resident registers:
   (constant ``c`` is lane ``c % 128`` of block ``c // 128``), DMA'd
   into VMEM when a step first needs that block;
 * a ``fori_loop`` supplies the trip count, with per-round constants
-  indexed as ``const + round * const_stride``.
+  indexed as ``const + round * const_stride``;
+* the key steps read the launch's per-lane **key operand**, DMA'd into
+  VMEM with each lane block like the state: ``XOR_KEY`` XORs one of its
+  128-row blocks into a 128-row window, and ``CLMUL`` forms the
+  carry-less product of a 128-row bit window with a key block, lane by
+  lane (bit-sliced: rows are coefficients, lanes are records).  The
+  window is copied once at each of the 8 sublane offsets; each 8-row
+  group ``q`` of the key block then XORs ``copy[s] & key[8q + s]`` into
+  the 136-row accumulator slice at row ``8q``, aligned, so a product is
+  128 AND/XOR passes over 17 row tiles whatever the values.
 
 The unit of VMEM residency is one lane block: a grid over the payload
 (lane) axis runs the complete program on each block in turn.  Lanes
@@ -68,11 +77,11 @@ from jax.experimental.pallas import tpu as pltpu
 # ("eq_const" appended last so pre-existing encoded streams keep their
 # numbering.)
 OPCODES = ("permute", "xor", "and", "andn", "add", "rotlv", "xor_const",
-           "eq_const")
+           "eq_const", "xor_key", "clmul")
 
 # Encoded layouts.  HBM arrays are 1-D and DMA'd in chunks whose start
 # and size are multiples of 1024 words (the 1-D HBM tiling).
-STEP_WORDS = 8           # (op, dst, a, b, plan, const, 0, 0)
+STEP_WORDS = 8           # (op, dst, a, b, plan, const, key, row)
 META_WORDS = 4           # per plan: (offset, count, xor fold, dense slot)
 STEP_CHUNK = 128         # steps per SMEM chunk (1024 words)
 ENTRY_WORDS = 3          # (dst row, src row, weight)
@@ -96,6 +105,8 @@ DENSE_MIN_SELECTS_PER_ROW = 4
 DENSE_VMEM_BUDGET_BYTES = 48 * 1024 * 1024
 DENSE_DTYPE = jnp.bfloat16   # 0/1 exact; the MXU's native input
 DENSE_ROWS = 128             # output rows per product tile
+KEY_BLOCK = 128              # key-operand rows per key block
+SUBLANES = 8                 # CLMUL's shifted copies of its window
 
 
 def control_digest(steps, consts, plan_parts=()) -> str:
@@ -119,33 +130,39 @@ def dense_table_bytes(n_pad: int) -> int:
 
 
 def vmem_bytes(n_pad: int, lane_block: int, n_regs: int,
-               itemsize: int, n_dense: int = 0) -> int:
+               itemsize: int, n_dense: int = 0, key_rows: int = 0) -> int:
     """VMEM one launch asks for: the register file plus the PERMUTE
-    accumulator at ``lane_block`` lanes, one constants block, and with
-    ``n_dense`` dense plans their tables and the source's bit block."""
+    accumulator at ``lane_block`` lanes, one constants block, with
+    ``n_dense`` dense plans their tables and the source's bit block, and
+    with a key operand of ``key_rows`` rows its block and CLMUL's
+    shifted window copies."""
     need = ((n_regs + 1) * n_pad * lane_block * itemsize
             + n_pad * LANES * 4 + _VMEM_SLACK_BYTES)
     if n_dense:
         need += (n_dense * dense_table_bytes(n_pad)
                  + n_pad * lane_block * jnp.dtype(DENSE_DTYPE).itemsize)
+    if key_rows:
+        need += ((key_rows + SUBLANES * (KEY_BLOCK + SUBLANES))
+                 * lane_block * itemsize)
     return need
 
 
 def lane_block(n_pad: int, d_pad: int, n_regs: int, itemsize: int,
-               n_dense: int = 0) -> int:
+               n_dense: int = 0, key_rows: int = 0) -> int:
     """The widest lane block (a multiple of 128 dividing ``d_pad``, at
-    most 1024) whose register file and dense tables fit the VMEM cap.
-    Raises when not even 128 lanes fit: the state is too tall for one
-    core's VMEM."""
+    most 1024) whose register file, dense tables and key operand fit the
+    VMEM cap.  Raises when not even 128 lanes fit: the state is too tall
+    for one core's VMEM."""
     for q in (8, 4, 2, 1):
         blk = LANES * q
         if (d_pad % blk == 0 and vmem_bytes(n_pad, blk, n_regs, itemsize,
-                                            n_dense) <= VMEM_CAP_BYTES):
+                                            n_dense, key_rows)
+                <= VMEM_CAP_BYTES):
             return blk
     raise ValueError(
         f"plan program state of {n_pad} rows x {n_regs} registers needs "
-        f"{vmem_bytes(n_pad, LANES, n_regs, itemsize, n_dense)} bytes of "
-        f"VMEM at {LANES} lanes; the cap is {VMEM_CAP_BYTES}")
+        f"{vmem_bytes(n_pad, LANES, n_regs, itemsize, n_dense, key_rows)} "
+        f"bytes of VMEM at {LANES} lanes; the cap is {VMEM_CAP_BYTES}")
 
 
 def _rotlv(v, amt):
@@ -156,17 +173,20 @@ def _rotlv(v, amt):
 
 
 def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, *refs,
-            n_steps, n_regs, rounds, const_stride, n_dense):
+            n_steps, n_regs, rounds, const_stride, n_dense, keyed):
     """The VM over one lane block: rounds { step chunks { steps } }.
 
     With ``n_dense`` dense plans, ``refs`` holds their tables' HBM
     operand before the output and, after the common scratch, the tables'
-    VMEM copy and the bf16 bit block of a product's source."""
-    if n_dense:
-        (dense_hbm, o_hbm, regs, acc, cblk, step_buf, ent_buf, cur_blk,
-         tables, bits) = refs
-    else:
-        o_hbm, regs, acc, cblk, step_buf, ent_buf, cur_blk = refs
+    VMEM copy and the bf16 bit block of a product's source.  With a key
+    operand (``keyed``), its HBM operand follows, and its VMEM block and
+    CLMUL's shifted window copies close the scratch."""
+    refs = list(refs)
+    dense_hbm = refs.pop(0) if n_dense else None
+    keys_hbm = refs.pop(0) if keyed else None
+    o_hbm, regs, acc, cblk, step_buf, ent_buf, cur_blk = refs[:7]
+    tables, bits = refs[7:9] if n_dense else (None, None)
+    kregs, shifted = refs[-2:] if keyed else (None, None)
     n_pad, width = acc.shape
     dtype = acc.dtype
     n_tiles = n_pad // ROW_TILE
@@ -184,6 +204,8 @@ def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, *refs,
             pltpu.sync_copy(dense_hbm, tables)
 
     pltpu.sync_copy(x_hbm.at[:, lanes], regs.at[0])
+    if keyed:
+        pltpu.sync_copy(keys_hbm.at[:, lanes], kregs)
     for r in range(1, n_regs):
         def zero(rows, r=r):
             regs[r, rows, :] = jnp.zeros((ROW_TILE, width), dtype)
@@ -294,6 +316,40 @@ def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, *refs,
             regs[dst, rows, :] = fn(regs[a, rows, :], column(rows))
         tiles(body)
 
+    def xor_key(dst, k, row):
+        rows = pl.ds(pl.multiple_of(row, SUBLANES), KEY_BLOCK)
+        block = pl.ds(pl.multiple_of(k * KEY_BLOCK, KEY_BLOCK), KEY_BLOCK)
+        regs[dst, rows, :] = regs[dst, rows, :] ^ kregs[block, :]
+
+    def clmul(dst, k, row):
+        """regs[dst] rows [row, row + 256) = bits [row, row + 128) times
+        key block k, carry-less: copy s of the window sits s rows down
+        in ``shifted[s]``, so the term of key row 8q + s lands on the
+        aligned accumulator slice [8q, 8q + 136)."""
+        r0 = pl.multiple_of(row, SUBLANES)
+        window = regs[dst, pl.ds(r0, KEY_BLOCK), :] & 1
+        edge = jnp.zeros((SUBLANES, width), dtype)
+        for s in range(SUBLANES):
+            shifted[s, pl.ds(0, SUBLANES), :] = edge
+            shifted[s, pl.ds(KEY_BLOCK, SUBLANES), :] = edge
+            shifted[s, pl.ds(s, KEY_BLOCK), :] = window
+        acc[pl.ds(0, 2 * KEY_BLOCK), :] = jnp.zeros((2 * KEY_BLOCK, width),
+                                                   dtype)
+        h0 = pl.multiple_of(k * KEY_BLOCK, KEY_BLOCK)
+
+        def group(q, carry):
+            base = pl.multiple_of(q * SUBLANES, SUBLANES)
+            rows = pl.ds(base, KEY_BLOCK + SUBLANES)
+            p = acc[rows, :]
+            for s in range(SUBLANES):
+                h = kregs[pl.ds(h0 + base + s, 1), :] & 1
+                p = p ^ (shifted[s] & h)
+            acc[rows, :] = p
+            return carry
+        jax.lax.fori_loop(0, KEY_BLOCK // SUBLANES, group, 0)
+        regs[dst, pl.ds(r0, 2 * KEY_BLOCK), :] = acc[pl.ds(0, 2 * KEY_BLOCK),
+                                                     :]
+
     binary = {"xor": lambda u, v: u ^ v,
               "and": lambda u, v: u & v,
               "andn": lambda u, v: ~u & v,
@@ -310,11 +366,19 @@ def _kernel(meta_ref, steps_hbm, ent_hbm, consts_hbm, x_hbm, *refs,
         b = step_buf[base + 3]
         p = step_buf[base + 4]
         c = step_buf[base + 5] + rnd * const_stride
+        k = step_buf[base + 6]
+        row = step_buf[base + 7]
         for code, name in enumerate(OPCODES):
+            if name in ("xor_key", "clmul") and not keyed:
+                continue
             @pl.when(op == code)
             def _(name=name):
                 if name == "permute":
                     permute(dst, a, p)
+                elif name == "xor_key":
+                    xor_key(dst, k, row)
+                elif name == "clmul":
+                    clmul(dst, k, row)
                 elif name in binary:
                     elementwise(dst, a, b, binary[name])
                 else:
@@ -347,6 +411,7 @@ def plan_program_pallas(
     consts: jax.Array,
     dense: Optional[jax.Array] = None,
     *,
+    keys: Optional[jax.Array] = None,
     n_steps: int,
     n_regs: int,
     rounds: int = 1,
@@ -365,15 +430,18 @@ def plan_program_pallas(
     fold, dense slot or -1 to walk); consts: (n_blocks, n_pad, 128)
     int32, constant ``c`` in lane ``c % 128`` of block ``c // 128``;
     dense: None, or (n_dense, n_pad, n_pad) DENSE_DTYPE 0/1 tables, one
-    per dense slot.  Returns (n_pad, d_pad) in state.dtype.
+    per dense slot; keys: None, or the (key_rows, d_pad) per-lane key
+    operand in state.dtype, key_rows a multiple of KEY_BLOCK, for
+    programs with key steps.  Returns (n_pad, d_pad) in state.dtype.
     """
     n_pad, d_pad = state.shape
     n_dense = 0 if dense is None else dense.shape[0]
+    key_rows = 0 if keys is None else keys.shape[0]
     itemsize = state.dtype.itemsize
-    width = lane_block(n_pad, d_pad, n_regs, itemsize, n_dense)
+    width = lane_block(n_pad, d_pad, n_regs, itemsize, n_dense, key_rows)
     kernel = functools.partial(
         _kernel, n_steps=n_steps, n_regs=n_regs, rounds=rounds,
-        const_stride=const_stride, n_dense=n_dense)
+        const_stride=const_stride, n_dense=n_dense, keyed=bool(key_rows))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     scratch = [
         pltpu.VMEM((n_regs, n_pad, width), state.dtype),
@@ -388,13 +456,18 @@ def plan_program_pallas(
         scratch += [pltpu.VMEM(dense.shape, DENSE_DTYPE),
                     pltpu.VMEM((n_pad, width), DENSE_DTYPE)]
         operands.append(dense)
+    if key_rows:
+        scratch += [pltpu.VMEM((key_rows, width), state.dtype),
+                    pltpu.VMEM((SUBLANES, KEY_BLOCK + SUBLANES, width),
+                               state.dtype)]
+        operands.append(keys)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(d_pad // width,),
         in_specs=[hbm] * (len(operands) - 1),
         out_specs=hbm,
         scratch_shapes=scratch)
-    need = vmem_bytes(n_pad, width, n_regs, itemsize, n_dense)
+    need = vmem_bytes(n_pad, width, n_regs, itemsize, n_dense, key_rows)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),

@@ -26,10 +26,11 @@ Design constraints, in order:
   ``repro`` — metrics feeding happens via a registered sink callback
   (``repro.obs.metrics`` installs itself on import).
 * **One clock with the device profile.**  A recording span named in
-  ``PROFILER_SPANS`` also enters ``jax.profiler.TraceAnnotation(name)``
-  (the name alone, no attributes), so the host phases that leave the
-  device idle show up in a ``jax.profiler`` trace on ``/host:CPU``,
-  in the recording thread's line, beside the device's operations.
+  ``PROFILER_SPANS`` or ``SESSION_PROFILER_SPANS`` also enters
+  ``jax.profiler.TraceAnnotation(name)`` (the name alone, no
+  attributes), so the host phases that leave the device idle show up
+  in a ``jax.profiler`` trace on ``/host:CPU``, in the recording
+  thread's line, beside the device's operations.
   Only leaf phases are mirrored: an enclosing span (``bucket_feed``,
   ``device_absorb``, ``resilient_execute``, ``registry_observe``,
   ``gcm_seal``, ``request``) would overlap every gap between device
@@ -94,11 +95,14 @@ def _truthy_env(name: str, default: str = "0") -> bool:
 
 
 # Spans that enter a jax.profiler.TraceAnnotation of their name while
-# they record: the leaf host phases of serving a bucket, the megakernel
-# launch, and garbage collection.
+# they record: the leaf host phases of serving any bucket, the
+# megakernel launch, and garbage collection.
 PROFILER_SPANS = frozenset({
     "feed_wait", "bucket_pack", "bucket_launch", "bucket_sync",
     "bucket_unpack", "program_launch", "gc_pause"})
+# The leaf phase that only AEAD buckets have, mirrored alike: the
+# lookup of each lane's session key material.
+SESSION_PROFILER_SPANS = frozenset({"key_lookup"})
 
 
 def _annotation(name: str):
@@ -253,7 +257,7 @@ class Span:
         if self.trace_id is None:
             self.trace_id = getattr(_TLS, "trace_id", None) or next(_IDS)
         stack.append(self)
-        if self.name in PROFILER_SPANS:
+        if self.name in PROFILER_SPANS or self.name in SESSION_PROFILER_SPANS:
             self.annotation = _annotation(self.name)
         self.t0 = time.perf_counter()
         return self

@@ -76,10 +76,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs as _obs
 from repro.core import crossbar as xb
+from repro.core import plan_program as pp
 from repro.core import telemetry
 from repro.core.resilience import (DeviceHealth, Fault, ResilientExecutor,
                                    TimeoutFault, default_chain)
 from repro.core.tuning import TuningTable
+from repro.crypto import aes as aes_mod
 from repro.crypto import gcm, keccak
 from repro.crypto.registry import REGISTRY
 from repro.dist.fault import (HeartbeatTracker, StragglerPolicy,
@@ -116,28 +118,48 @@ def _dummy_payload(n_blocks: int) -> bytes:
 
 
 # AEAD records ride the same byte-payload admission path as digests.
-# Wire format for op="gcm_seal": nonce(12) || aad_len:u32be || aad ||
-# plaintext; the result is ciphertext || 16-byte tag.  The bucket key
-# is the exact (pt_len, aad_len) record geometry — that is what one
-# fused GCM program instance covers, so a bucket maps 1:1 onto ONE
-# program launch with the batch as payload lanes.
+# Wire format for op="gcm_seal": nonce(12) || aad_len:u32be ||
+# [session:u32be] || aad || plaintext, the session index present when
+# the top bit of the length word is set; the result is ciphertext ||
+# 16-byte tag.  The bucket key is the exact (pt_len, aad_len) record
+# geometry — that is what one fused GCM program covers, whatever the
+# keys, so a bucket maps 1:1 onto ONE program launch with the batch as
+# payload lanes, each lane keyed by its record's session.
 
-def encode_aead_record(nonce: bytes, plaintext: bytes,
-                       aad: bytes = b"") -> bytes:
-    """Pack one seal request for ``submit(..., op='gcm_seal')``."""
+_SESSION_FLAG = 1 << 31
+
+
+def encode_aead_record(nonce: bytes, plaintext: bytes, aad: bytes = b"",
+                       session: Optional[int] = None) -> bytes:
+    """Pack one seal request for ``submit(..., op='gcm_seal')``, sealed
+    under the engine's session ``session`` (session 0 when None)."""
     if len(nonce) != gcm.IV_BYTES:
         raise ValueError(f"AEAD nonce must be {gcm.IV_BYTES} bytes")
-    return nonce + len(aad).to_bytes(4, "big") + aad + plaintext
+    if session is None:
+        return nonce + len(aad).to_bytes(4, "big") + aad + plaintext
+    return (nonce + (len(aad) | _SESSION_FLAG).to_bytes(4, "big")
+            + session.to_bytes(4, "big") + aad + plaintext)
+
+
+def _aead_header(payload: bytes) -> tuple:
+    """(aad_len, session, offset of the AAD) of one wire record."""
+    word = int.from_bytes(payload[12:16], "big")
+    if word & _SESSION_FLAG:
+        return (word ^ _SESSION_FLAG,
+                int.from_bytes(payload[16:20], "big"), 20)
+    return word, 0, 16
 
 
 def _decode_aead_record(payload: bytes) -> tuple:
-    aad_len = int.from_bytes(payload[12:16], "big")
-    return (payload[:12], payload[16 + aad_len:], payload[16:16 + aad_len])
+    """(nonce, plaintext, aad, session) of one wire record."""
+    aad_len, session, off = _aead_header(payload)
+    return (payload[:12], payload[off + aad_len:],
+            payload[off:off + aad_len], session)
 
 
 def _aead_bucket(payload: bytes) -> tuple:
-    aad_len = int.from_bytes(payload[12:16], "big")
-    return (len(payload) - 16 - aad_len, aad_len)   # (pt_len, aad_len)
+    aad_len, _, off = _aead_header(payload)
+    return (len(payload) - off - aad_len, aad_len)   # (pt_len, aad_len)
 
 
 _RID_COUNTER = itertools.count(1)
@@ -254,9 +276,13 @@ class BatchingOptions:
     double_buffer: bool = True
     # Measured backend table; None creates a fresh engine-local one.
     tuning: Optional[TuningTable] = None
-    # Engine-held AES-128 key for op="gcm_seal" buckets (per-record
-    # keys would defeat bucketing: the fused program is per-key).
+    # AES-128 keys of the op="gcm_seal" sessions, installed on the
+    # device when the engine is built: session i is aead_keys[i], or,
+    # with no aead_keys, session 0 is aead_key.  A record names its
+    # session (encode_aead_record) or is sealed under session 0; one
+    # bucket's lanes may each use another session.
     aead_key: bytes = b"\x00" * 16
+    aead_keys: Sequence[bytes] = ()
     # Partial-batch recovery on a mesh: execute each shard's lane
     # window as its own journaled unit, so a device fault mid-batch
     # salvages completed shards and replays only the lost lanes on the
@@ -410,32 +436,59 @@ def _keccak_registry_keys(backend: str) -> tuple:
     return ("keccak/rho_pi",)
 
 
-def _bucket_seal(payloads: Sequence[bytes], backend: str, key: bytes, *,
+class SessionTable:
+    """The AEAD sessions' key material on the device, installed once:
+    ``gcm.key_material`` rows (round keys and H per session), and the
+    keys on the host for the chained rungs."""
+
+    def __init__(self, keys: Sequence[bytes]):
+        self.keys = tuple(bytes(k) for k in keys)
+        self.device = jnp.asarray(gcm.key_material(self.keys))
+        telemetry.incr("serve_sessions_installed", len(self.keys))
+
+    def __len__(self) -> int:
+        return len(self.keys)
+
+
+def _bucket_seal(payloads: Sequence[bytes], backend: str,
+                 sessions: SessionTable, live: int, *,
                  fixed_latency: bool,
                  interpret: Optional[bool] = None,
                  phase=gcm.no_phase) -> list:
-    """Seal one AEAD bucket: decode the wire records and run the whole
-    batch as ONE fused GCM program launch (backend='megakernel'), or the
-    chained per-block lowering on a crossbar backend when degraded.
-    ``phase("pack")`` opens the decode, which the seal's own bit
-    packing continues; the seal tells ``phase`` the stages after it."""
+    """Seal one AEAD bucket: look up each lane's session, decode the
+    wire records and run the whole batch as ONE fused GCM program launch
+    (backend='megakernel'), its lanes' key material gathered from the
+    device session table, or the chained per-block lowering on a
+    crossbar backend when degraded.  ``phase("key_lookup")`` opens the
+    lookup and ``phase("pack")`` the decode, which the seal's own bit
+    packing continues; the seal tells ``phase`` the stages after it.
+    ``live`` leading lanes are requests, the rest padding."""
+    phase("key_lookup")
+    idx = np.zeros(pp.lane_pad(len(payloads)), np.int32)
+    idx[:len(payloads)] = [_aead_header(p)[1] for p in payloads]
+    telemetry.incr("serve_bucket_sessions", len(set(idx[:live].tolist())))
+    megakernel = backend == "megakernel"
+    lanes = (gcm.session_keys(sessions.device, idx) if megakernel
+             else [sessions.keys[i] for i in idx[:len(payloads)]])
     phase("pack")
     recs = [_decode_aead_record(p) for p in payloads]
-    be = "fused" if backend == "megakernel" else backend
-    return gcm.aes128_gcm_seal_batch(
-        key, [r[0] for r in recs], [r[1] for r in recs],
-        [r[2] for r in recs], backend=be,
-        fixed_latency=fixed_latency and be == "fused",
-        interpret=interpret, phase=phase)
+    args = ([r[0] for r in recs], [r[1] for r in recs],
+            [r[2] for r in recs])
+    if megakernel:
+        return gcm.seal_lanes(lanes, *args, fixed_latency=fixed_latency,
+                              interpret=interpret, phase=phase)
+    return gcm.aes128_gcm_seal_batch(lanes, *args, backend=backend,
+                                     interpret=interpret, phase=phase)
 
 
-def _gcm_registry_keys(key: bytes, pt_len: int, aad_len: int):
-    """Quarantine targets for a gcm_seal bucket: the fused program on
-    the megakernel rung, the GHASH plan on the chained rungs."""
+def _gcm_registry_keys(pt_len: int, aad_len: int):
+    """Quarantine targets for a gcm_seal bucket: the fused program of
+    its geometry on the megakernel rung, the AES plans on the chained
+    rungs (their GHASH plans are per session key)."""
     def keys(backend: str) -> tuple:
         if backend == "megakernel":
-            return (gcm._program_key(key, pt_len, aad_len, False),)
-        return (gcm._ghash_plan_key(gcm._hash_key(key), "horner", 1),)
+            return (gcm._program_key(pt_len, aad_len, False),)
+        return aes_mod._ensure_plans(False, True)
     return keys
 
 
@@ -446,6 +499,8 @@ class _Phases:
     ended by the next one starting rather than by a ``with`` block
     because the stages of a GCM seal live in two modules (the record
     decode here, the bit packing in ``crypto.gcm``) and make one phase.
+    ``phase("key_lookup")``, a GCM bucket's first phase, opens the span
+    ``key_lookup``.
     """
 
     __slots__ = ("_attrs", "_open")
@@ -459,7 +514,9 @@ class _Phases:
             self._open.__exit__(None, None, None)
             self._open = None
         if name is not None:
-            self._open = _obs.span("bucket_" + name, **self._attrs)
+            self._open = _obs.span(
+                name if name == "key_lookup" else "bucket_" + name,
+                **self._attrs)
             self._open.__enter__()
 
 
@@ -497,6 +554,9 @@ class BatchingEngine:
             self.device_health = DeviceHealth(len(self._mesh_devices))
         # Partial-batch recovery journal: completed lanes by request id.
         self.journal = ResultJournal(cap=options.journal_cap)
+        # The gcm_seal sessions, on the device from here on.
+        self.sessions = SessionTable(tuple(options.aead_keys)
+                                     or (options.aead_key,))
         # Measured backend tuning (core/tuning.py): records every bucket
         # wall time, rank-orders the fallback chain, and backs
         # crossbar's backend="auto" for the passes inside each absorb.
@@ -589,6 +649,11 @@ class BatchingEngine:
         if op not in _SUPPORTED_OPS:
             raise ValueError(f"unsupported op {op!r}; supported: "
                              f"{_SUPPORTED_OPS}")
+        if op == "gcm_seal":
+            session = _aead_header(payload)[1]
+            if session >= len(self.sessions):
+                raise ValueError(f"record names session {session}; the "
+                                 f"engine has {len(self.sessions)}")
         if timeout_s is None:
             timeout_s = self.opt.default_timeout_s
         deadline = (None if timeout_s is None
@@ -757,10 +822,11 @@ class BatchingEngine:
                         lanes=len(batch))
         if op == "gcm_seal":
             def work(backend: str) -> list:
-                return _bucket_seal(data, backend, self.opt.aead_key,
+                return _bucket_seal(data, backend, self.sessions,
+                                    len(batch),
                                     fixed_latency=self.opt.fixed_latency,
                                     interpret=self.interpret, phase=phase)
-            registry_keys = _gcm_registry_keys(self.opt.aead_key, *geom)
+            registry_keys = _gcm_registry_keys(*geom)
         else:
             def work(backend: str) -> list:
                 return _absorb_digests(data, backend,

@@ -20,7 +20,9 @@ from repro.core.resilience import (CircuitBreaker, LaunchFault,
 from repro.crypto.registry import REGISTRY
 from repro.crypto import gcm
 from repro.serve.batching import (BatchingEngine, BatchingOptions, Cancelled,
-                                  Overloaded, _dummy_payload, _n_blocks,
+                                  Overloaded, _aead_bucket,
+                                  _decode_aead_record, _dummy_payload,
+                                  _gcm_registry_keys, _n_blocks,
                                   encode_aead_record)
 
 pytestmark = pytest.mark.chaos
@@ -242,6 +244,69 @@ class TestAEADRecords:
         assert h.result(timeout=5) == hashlib.sha3_256(msg).digest()
         assert s.result(timeout=5) == gcm.aes128_gcm_seal(
             self.KEY, b"\x01" * 12, b"seal me", backend="einsum")
+
+
+class TestAEADSessions:
+    """Per-session keys: the sessions live on the device, a record names
+    its session, and one bucket's lanes seal under different keys."""
+
+    KEYS = [bytes((17 * r + 5 * i + 1) & 0xFF for i in range(16))
+            for r in range(8)]
+
+    def _records(self, sessions):
+        return [(bytes([s + 1]) * 12, bytes([0x30 + s]) * 17, b"\x17\x03\x03"
+                 + bytes([0, 33]), s) for s in sessions]
+
+    @pytest.mark.parametrize("chain", [("megakernel",), ("einsum",)])
+    def test_one_bucket_seals_each_lane_under_its_session(self, chain):
+        from cryptography.hazmat.primitives.ciphers.aead import AESGCM
+        eng = _engine(aead_keys=self.KEYS, max_batch=8, chain=chain)
+        recs = self._records([7, 3, 0, 5, 3, 1, 6, 2])
+        before = telemetry.snapshot()
+        reqs = [eng.submit(encode_aead_record(*r), op="gcm_seal")
+                for r in recs]
+        assert eng.run_once() == 8               # one bucket, one launch
+        for req, (nonce, pt, aad, s) in zip(reqs, recs):
+            assert req.result(timeout=5) == AESGCM(self.KEYS[s]).encrypt(
+                nonce, pt, aad)
+            assert req.backend == chain[0]
+        snap = telemetry.snapshot()
+        assert (snap["serve_bucket_sessions"]
+                - before.get("serve_bucket_sessions", 0)) == 7
+        assert len(eng.batch_log) == 1
+
+    def test_record_without_session_uses_session_zero(self):
+        from cryptography.hazmat.primitives.ciphers.aead import AESGCM
+        before = telemetry.counter("serve_sessions_installed")
+        eng = _engine(aead_keys=self.KEYS, max_batch=8,
+                      chain=("megakernel",))
+        assert telemetry.counter("serve_sessions_installed") - before == 8
+        nonce, pt, aad, _ = self._records([0])[0]
+        req = eng.submit(encode_aead_record(nonce, pt, aad), op="gcm_seal")
+        _drain(eng)
+        assert req.result(timeout=5) == AESGCM(self.KEYS[0]).encrypt(
+            nonce, pt, aad)
+
+    def test_unknown_session_is_refused_at_submit(self):
+        eng = _engine(aead_keys=self.KEYS[:2])
+        rec = encode_aead_record(b"\x00" * 12, b"x", session=2)
+        with pytest.raises(ValueError, match="session 2"):
+            eng.submit(rec, op="gcm_seal")
+        assert eng.queue_depth() == 0
+
+    def test_wire_format_round_trip(self):
+        nonce, pt, aad = b"\x05" * 12, b"payload!", b"hdr"
+        plain = encode_aead_record(nonce, pt, aad)
+        assert plain == nonce + (3).to_bytes(4, "big") + aad + pt
+        named = encode_aead_record(nonce, pt, aad, session=65535)
+        assert _decode_aead_record(plain) == (nonce, pt, aad, 0)
+        assert _decode_aead_record(named) == (nonce, pt, aad, 65535)
+        assert _aead_bucket(plain) == _aead_bucket(named) == (8, 3)
+
+    def test_registry_keys_follow_the_geometry_alone(self):
+        keys = _gcm_registry_keys(17, 5)
+        assert keys("megakernel") == (gcm._program_key(17, 5, False),)
+        assert all(k.startswith("aes/") for k in keys("einsum"))
 
 
 class TestAEADChaosSweep:

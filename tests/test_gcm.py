@@ -143,13 +143,24 @@ class TestGhashPrimitive:
         assert one == 1
         assert per_block == len(data) // 16
 
-    def test_mul_bits_matches_field_oracle(self):
+    def test_clmul_reduction_matches_field_oracle(self):
+        """The fused program's multiply by H: the carry-less product of
+        the factor in coefficient order (``_coeff_row``) with H, reduced
+        by ``_reduction``'s select lists, read back in block order."""
         h = gcm._hash_key(KEY)
-        m = gcm._mul_bits(h)
         x = bytes(range(16))
-        xb_ = np.unpackbits(np.frombuffer(x, np.uint8),
-                            bitorder="little")
-        got = np.packbits((m @ xb_) % 2, bitorder="little").tobytes()
+        bits = np.unpackbits(np.frombuffer(x, np.uint8), bitorder="little")
+        factor = sum(int(bits[gcm._coeff_row(c)]) << c for c in range(128))
+        prod = 0
+        for i in range(128):
+            if (h >> i) & 1:
+                prod ^= factor << i
+        red = gcm._reduction()
+        out = np.zeros(128, np.uint8)
+        for r in range(128):
+            out[r] = sum((prod >> k) & 1
+                         for k in red[gcm._coeff_row(r)]) & 1
+        got = np.packbits(out, bitorder="little").tobytes()
         assert got == _ghash_ref(_aes_ref(KEY, b"\x00" * 16), x)
 
 
@@ -199,12 +210,76 @@ class TestFusedDifferential:
                 KEY, [b"\x00" * 12] * 2, [b"a", b"bb"])
 
 
+KEYS8 = [bytes((31 * r + 7 * i + 3) & 0xFF for i in range(16))
+         for r in range(8)]
+
+
+class TestPerLaneKeys:
+    """One program per geometry, each lane under its own key: the key
+    material is the launch's key operand, not part of the program."""
+
+    def test_key_material_matches_per_key_expansion(self):
+        keys = KEYS8 + [b"\x00" * 16, _K34]
+        km = gcm.key_material(keys)
+        assert km.shape == (len(keys), gcm.KEY_BYTES)
+        for row, key in zip(km, keys):
+            rks = aes_mod.key_expansion(key).astype(np.uint8).reshape(-1)
+            assert row[:176].tobytes() == rks.tobytes()
+            assert row[176:].tobytes() == gcm._hash_key(key).to_bytes(
+                16, "little")
+        lanes = gcm.key_lanes(keys)
+        idx = jnp.asarray([3, 0, 9, 3], jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(gcm.session_keys(jnp.asarray(km), idx)),
+            lanes[:, [3, 0, 9, 3]])
+
+    @pytest.mark.parametrize("backend", ["fused", "einsum"])
+    @pytest.mark.parametrize("pt_len,aad_len", [(17, 5), (48, 16)])
+    def test_eight_records_under_eight_keys(self, backend, pt_len,
+                                            aad_len):
+        from cryptography.hazmat.primitives.ciphers.aead import AESGCM
+        ivs, pts, aads = _vecs(pt_len, aad_len, b=8)
+        sealed = gcm.aes128_gcm_seal_batch(KEYS8, ivs, pts, aads,
+                                           backend=backend)
+        for key, iv, pt, aad, got in zip(KEYS8, ivs, pts, aads, sealed):
+            assert got == AESGCM(key).encrypt(iv, pt, aad)
+        assert gcm.aes128_gcm_open_batch(KEYS8, ivs, sealed, aads,
+                                         backend=backend) == pts
+        with pytest.raises(gcm.InvalidTagError) as ei:
+            gcm.aes128_gcm_open_batch(KEYS8[1:] + KEYS8[:1], ivs, sealed,
+                                      aads, backend=backend)
+        assert ei.value.indices == tuple(range(8))
+
+    def test_two_keys_give_one_program_and_one_compile(self):
+        """The program, its encoding, its dense slots and its registry
+        fingerprint are those of the geometry, whatever the key, and a
+        second key launches the executable the first one compiled."""
+        ivs, pts, aads = _vecs(29, 7, b=2)
+        prog_key, prog, _ = gcm.gcm_program(29, 7)
+        assert prog_key == "gcm/aes128/seal/m2.13a1"
+        seen = []
+        misses = pp.program_cache_info()["misses"]
+        for key in KEYS8[:2]:
+            with telemetry.delta() as d:
+                gcm.aes128_gcm_seal_batch(key, ivs, pts, aads,
+                                          fixed_latency=True)
+            built = gcm.build_gcm_program(29, 7)[0]
+            assert gcm.gcm_program(29, 7)[1] is prog
+            seen.append((pp.encode_steps(built).tobytes(),
+                         pp.dense_slots(built),
+                         [np.asarray(p.idx).tobytes() for p in built.plans],
+                         REGISTRY.program_fingerprint(prog_key),
+                         d()["megakernel_entries_walked"]))
+        assert pp.program_cache_info()["misses"] - misses == 1
+        assert seen[0] == seen[1]
+
+
 class TestLaunchLedger:
     def test_batch_seal_is_one_launch(self):
         """B=32 multi-block records: the whole batch seals in ONE
         program launch, with the avoided chained passes ledgered."""
         ivs, pts, aads = _vecs(48, 16, b=32)
-        gcm.gcm_program(KEY, 48, 16)            # warm the program cache
+        gcm.gcm_program(48, 16)                 # warm the program cache
         from repro.core import crossbar as xb
         l0 = pp.program_launch_count()
         a0 = xb.apply_call_count()
@@ -259,22 +334,24 @@ class TestConstantTime:
         """The complete fused seal — every AES round, the counter
         constants, GHASH absorb, and the tag — abstract-evaluates with
         payload tracers: no value-dependent host sync anywhere."""
-        fn, lay = gcm.seal_device_fn(KEY, 53, 18)
+        fn, lay = gcm.seal_device_fn(53, 18)
         out = REGISTRY.audit_constant_time(
-            "gcm_seal_audit", fn, jnp.zeros((lay["n"], 8), jnp.int32))
+            "gcm_seal_audit", fn, jnp.zeros((lay["n"], 8), jnp.int32),
+            jnp.zeros((gcm.KEY_ROWS, 8), jnp.int32))
         assert out.shape == (lay["n"], 8)
 
     def test_audit_open_program(self):
-        fn, lay = gcm.seal_device_fn(KEY, 32, 0, open_mode=True)
+        fn, lay = gcm.seal_device_fn(32, 0, open_mode=True)
         REGISTRY.audit_constant_time(
-            "gcm_open_audit", fn, jnp.zeros((lay["n"], 2), jnp.int32))
+            "gcm_open_audit", fn, jnp.zeros((lay["n"], 2), jnp.int32),
+            jnp.zeros((gcm.KEY_ROWS, 2), jnp.int32))
 
     def test_program_passes_property(self):
         """The program's pass ledger is geometry-determined: trips =
         m+1 blocks, each a full AES-128 (4 permutes/round) plus the
         absorb pipeline — payload never changes it."""
-        _, prog, _ = gcm.gcm_program(KEY, 48, 16)
-        _, prog2, _ = gcm.gcm_program(KEY, 48, 16)
+        _, prog, _ = gcm.gcm_program(48, 16)
+        _, prog2, _ = gcm.gcm_program(48, 16)
         assert prog is prog2                    # registry-cached
         assert prog.rounds == 1
         assert prog.passes == sum(

@@ -425,10 +425,17 @@ class TestBucketLifecycle:
                 assert sp.thread_name == (PREP if on_prep else FEED), name
                 assert sp.attrs["op"] == op
                 assert sp.attrs["lanes"] == f.attrs["lanes"]
-            # The feed thread's phases follow one another inside it.
+            # The feed thread's phases follow one another inside it; a
+            # GCM bucket looks its lanes' session keys up first.
             inner = ["bucket_launch", "bucket_sync", "bucket_unpack"]
             if op == "gcm_seal":
                 inner.insert(0, "bucket_pack")
+                (lookup,) = [s for s in _by_name(spans, "key_lookup")
+                             if s.trace_id == f.trace_id]
+                assert lookup.thread_name == FEED
+                assert lookup.attrs["lanes"] == f.attrs["lanes"]
+                inner.insert(0, "key_lookup")
+                mine["key_lookup"] = [lookup]
             seq = [mine[n][0] for n in inner]
             assert f.t0 <= seq[0].t0
             assert all(a.t1 <= b.t0 for a, b in zip(seq, seq[1:]))

@@ -178,14 +178,16 @@ class TestDifferential:
         if name == "keccak":
             prog = kk.megakernel_program()
         elif name == "gcm_seal":
-            _, prog, _ = gcm.gcm_program(bytes(range(16)), 16, 16)
+            _, prog, _ = gcm.gcm_program(16, 16)
         else:
             prog = _many_consts_program()
         x = _bits(6, (prog.n, 300))
+        keys = _bits(8, (prog.key_rows, 300)) if prog.key_rows else None
         np.testing.assert_array_equal(
-            np.asarray(pp.run_program(prog, x, backend="chained",
+            np.asarray(pp.run_program(prog, x, keys=keys, backend="chained",
                                       pass_backend="reference")),
-            np.asarray(pp.run_program(prog, x, backend="megakernel")))
+            np.asarray(pp.run_program(prog, x, keys=keys,
+                                      backend="megakernel")))
 
     def test_weighted_real_program(self):
         rng = np.random.default_rng(5)
@@ -300,6 +302,82 @@ def _dense_gf2_plans(n, uses):
     return b.build()
 
 
+def _clmul_int(x: int, h: int) -> int:
+    out = 0
+    for i in range(x.bit_length()):
+        if (x >> i) & 1:
+            out ^= h << i
+    return out
+
+
+def _column_int(bits) -> int:
+    return sum(int(b) << i for i, b in enumerate(bits))
+
+
+class TestKeySteps:
+    """XOR_KEY and CLMUL: the per-lane key operand, on both executors."""
+
+    N, LANES = 392, 5
+
+    def _program(self):
+        b = pp.ProgramBuilder("keyed", self.N, n_regs=2)
+        b.xor_key(0, 2, row=8)           # rows 8..135 ^= key block 2
+        b.clmul(0, 1, row=128)           # rows 128..383 = x (x) block 1
+        b.xor(1, 0, 0)
+        return b.build()
+
+    @pytest.mark.parametrize("backend", ["chained", "megakernel"])
+    def test_clmul_matches_python_carryless_product(self, backend):
+        prog = self._program()
+        assert prog.key_rows == 3 * pp.KEY_BLOCK
+        rng = np.random.default_rng(21)
+        x = rng.integers(0, 2, (self.N, self.LANES))
+        keys = rng.integers(0, 2, (prog.key_rows, self.LANES))
+        got = np.asarray(pp.run_program(prog, jnp.asarray(x, jnp.int32),
+                                        keys=jnp.asarray(keys, jnp.int32),
+                                        backend=backend))
+        want = x.copy()
+        want[8:136] ^= keys[256:384]
+        for lane in range(self.LANES):
+            prod = _clmul_int(_column_int(want[128:256, lane]),
+                              _column_int(keys[128:256, lane]))
+            want[128:384, lane] = [(prod >> k) & 1 for k in range(256)]
+        np.testing.assert_array_equal(got, want)
+
+    def test_keys_padded_to_the_lane_block(self):
+        prog = self._program()
+        x = _bits(3, (self.N, 2))
+        keys = _bits(4, (prog.key_rows, pp.lane_pad(2)))
+        for backend in ("megakernel", "chained"):
+            np.testing.assert_array_equal(
+                np.asarray(pp.run_program(prog, x, keys=keys,
+                                          backend=backend)),
+                np.asarray(pp.run_program(prog, x, keys=keys[:, :2],
+                                          backend="chained")))
+
+    def test_key_operand_is_checked(self):
+        prog = self._program()
+        x = _bits(5, (self.N, 2))
+        with pytest.raises(ValueError, match="key operand"):
+            pp.run_program(prog, x)
+        with pytest.raises(ValueError, match="key operand"):
+            pp.run_program(prog, x, keys=_bits(5, (128, 2)))
+        with pytest.raises(ValueError, match="key operand"):
+            pp.run_program(prog, x, keys=_bits(5, (prog.key_rows, 3)))
+        with pytest.raises(ValueError, match="reads no key operand"):
+            pp.run_program(_synthetic_program(), _words(1, (16, 2)),
+                           keys=_bits(5, (128, 2)))
+
+    @pytest.mark.parametrize("step", [
+        pp.Step(pp.CLMUL, 0, 1, key=0, row=0),      # not in place
+        pp.Step(pp.XOR_KEY, 0, 0, key=0, row=4),    # unaligned window
+        pp.Step(pp.CLMUL, 0, 0, key=0, row=144),    # past the state
+        pp.Step(pp.XOR_KEY, 0, 0, row=0)])          # no key block
+    def test_rejects_bad_key_step(self, step):
+        with pytest.raises(ValueError, match="key step"):
+            pp.PlanProgram("bad", self.N, (step,), (), None, 2)
+
+
 class TestDensePermute:
     """Dense GF(2) PERMUTEs (one MXU product) against the chained
     executor, the rule that picks them, and the launch counters."""
@@ -316,14 +394,15 @@ class TestDensePermute:
     @pytest.mark.parametrize("pt_len,aad_len", [(16, 16), (17, 5)])
     def test_gcm_programs(self, pt_len, aad_len, open_mode):
         """Seal and open, m=1 and m=2 with a 1-byte last block."""
-        _, prog, _ = gcm.gcm_program(bytes(range(16)), pt_len, aad_len,
-                                     open_mode=open_mode)
+        _, prog, _ = gcm.gcm_program(pt_len, aad_len, open_mode=open_mode)
         assert max(pp.dense_slots(prog)) >= 0
         x = _bits(32, (prog.n, 3))
+        keys = _bits(33, (prog.key_rows, 3))
         np.testing.assert_array_equal(
-            np.asarray(pp.run_program(prog, x, backend="chained",
+            np.asarray(pp.run_program(prog, x, keys=keys, backend="chained",
                                       pass_backend="reference")),
-            np.asarray(pp.run_program(prog, x, backend="megakernel")))
+            np.asarray(pp.run_program(prog, x, keys=keys,
+                                      backend="megakernel")))
 
     @pytest.mark.parametrize("values", ["bits", "int32", "uint32"])
     def test_random_gf2_plan_parity(self, values):
@@ -367,7 +446,7 @@ class TestDensePermute:
         assert [pp.dense_slots(keccak)[p] for p in steps] == [0, -1, -1]
         assert keccak.plans[steps[1]].semiring is not GF2
 
-        _, seal, _ = gcm.gcm_program(bytes(range(16)), 17, 5)
+        _, seal, _ = gcm.gcm_program(17, 5)
         slots = pp.dense_slots(seal)
         plans = _permute_plans(seal)
         # d1, d2, ctr, then round 1: nspread, psel, hirep, nfold, linear
@@ -376,9 +455,12 @@ class TestDensePermute:
                    if s.op == pp.PERMUTE and (s.dst, s.a) == (2, 1)]
         full, masked = absorbs
         assert seal.plans[nspread].semiring is not GF2
-        assert slots[psel] >= 0 and slots[full] >= 0
-        assert sorted(s for s in slots if s >= 0) == [0, 1]
-        for walked in (d1, nspread, hirep, nfold, linear, masked,
+        # With H per lane, an absorb reads about 6 selects a factor row
+        # (the reduction and the ciphertext bit), not the 64 of a baked
+        # multiply-by-H matrix: only the S-box's psel is dense.
+        assert slots[psel] >= 0
+        assert sorted(s for s in slots if s >= 0) == [0]
+        for walked in (d1, nspread, hirep, nfold, linear, full, masked,
                        plans[-1]):
             assert slots[walked] == -1
 

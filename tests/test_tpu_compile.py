@@ -22,7 +22,6 @@ from repro.kernels import crossbar_permute as cp
 from repro.kernels import plan_program_kernel as ppk
 
 N, D = 4096, 512          # crossbar kernels: the chip smoke's served size
-GCM_KEY = bytes(range(16))
 
 
 @pytest.fixture(scope="module")
@@ -73,19 +72,28 @@ def test_sparse_crossbar_compiles(one_chip):
 
 
 def _compile_program(program, lanes, sharding, n_dense=None):
-    """Compile ``program`` at ``lanes`` lanes.  Where ``n_dense`` is
-    given, check that as many dense plans ride as the tables operand and
-    that their VMEM need fits one block of ``lanes``."""
+    """Compile ``program`` at ``lanes`` lanes, with its key operand when
+    it reads one.  Where ``n_dense`` is given, check that as many dense
+    plans ride as the tables operand and that their VMEM need, and the
+    key operand's, fits one block of ``lanes``."""
     n_pad, control, static = pp.encode_program(program)
     if n_dense is not None:
         assert len(control) == 4 + bool(n_dense)
     if n_dense:
         assert control[-1].shape == (n_dense, n_pad, n_pad)
-        assert ppk.lane_block(n_pad, lanes, program.n_regs, 4,
-                              n_dense) == lanes
-    fn = functools.partial(ppk.plan_program_pallas, **static)
-    return _compile(fn, _spec((n_pad, lanes), jnp.int32, sharding),
-                    *(_spec(c.shape, c.dtype, sharding) for c in control))
+        assert ppk.lane_block(n_pad, lanes, program.n_regs, 4, n_dense,
+                              program.key_rows) == lanes
+    keyed = bool(program.key_rows)
+    specs = [_spec((n_pad, lanes), jnp.int32, sharding)]
+    specs += [_spec(c.shape, c.dtype, sharding) for c in control]
+    if keyed:
+        specs.append(_spec((program.key_rows, lanes), jnp.int32, sharding))
+
+    def fn(state, *rest):
+        ctrl, keys = (rest[:-1], rest[-1]) if keyed else (rest, None)
+        return ppk.plan_program_pallas(state, *ctrl, keys=keys, **static)
+
+    return _compile(fn, *specs)
 
 
 def test_megakernel_keccak_compiles(one_chip):
@@ -93,16 +101,17 @@ def test_megakernel_keccak_compiles(one_chip):
 
 
 def test_megakernel_gcm_seal_compiles(one_chip):
-    _, program, _ = gcm.gcm_program(GCM_KEY, 1024, 16)
-    # 8,800 rows: one table would pass the dense budget, so all walk.
+    _, program, _ = gcm.gcm_program(1024, 16)
+    # 9,056 rows: one table would pass the dense budget, so all walk.
     _compile_program(program, 128, one_chip, n_dense=0)
 
 
 def test_megakernel_gcm_seal_dense_compiles(one_chip):
-    """The served TLS record geometry (17 B, 5 B header): the S-box and
-    full-block absorb plans run as products, at the widest lane block."""
-    _, program, _ = gcm.gcm_program(GCM_KEY, 17, 5)
-    _compile_program(program, 1024, one_chip, n_dense=2)
+    """The served TLS record geometry (17 B, 5 B header): the S-box
+    plan runs as a product, with the lanes' key operand and CLMUL, at
+    the widest lane block."""
+    _, program, _ = gcm.gcm_program(17, 5)
+    _compile_program(program, 1024, one_chip, n_dense=1)
 
 
 def test_megakernel_custom_call_is_named(one_chip):
