@@ -257,9 +257,15 @@ class BatchingOptions:
     runs every bucket under the crypto registry's observation contract;
     drift then surfaces as ``DriftFault`` and is quarantined rather
     than poisoning the pinned caches.
+
+    ``max_batch`` caps the live requests of one bucket at one
+    megakernel lane block (``pp.lane_pad(1)`` = 128 lanes): a launch
+    runs a whole block whatever its live lanes, so a smaller cap pays
+    a full launch and a full feed cycle for part of a block.  A bucket
+    still takes only what is queued and never waits to fill.
     """
 
-    max_batch: int = 8
+    max_batch: int = pp.lane_pad(1)
     max_queue: int = 1024
     default_timeout_s: Optional[float] = None
     poll_interval_s: float = 0.02

@@ -78,6 +78,50 @@ class TestBuckets:
         assert all(r.done() for r in reqs)
 
 
+class TestBucketCapacity:
+    """By default a bucket holds up to one megakernel lane block: the
+    width every launch runs at, so a backlog rides one launch."""
+
+    def test_default_cap_is_one_megakernel_lane_block(self):
+        from repro.core import plan_program as pp
+        from repro.kernels import plan_program_kernel as ppk
+        assert BatchingOptions().max_batch == ppk.LANES == pp.lane_pad(1)
+
+    def test_backlog_leaves_as_one_full_block(self):
+        eng = _engine()
+        msgs = [b"m%03d" % i for i in range(100)]
+        reqs = [eng.submit(m) for m in msgs]
+        assert eng.run_once() == 100
+        assert [shape for _, shape, _, _ in eng.batch_log] == [(128, 1)]
+        assert telemetry.counter("serve_padded_lanes") == 28
+        for m, r in zip(msgs, reqs):
+            assert r.result(timeout=0) == hashlib.sha3_256(m).digest()
+
+    def test_bucket_never_waits_to_fill(self):
+        eng = _engine()
+        reqs = [eng.submit(bytes([i])) for i in range(3)]
+        assert eng.run_once() == 3
+        assert all(r.done() for r in reqs)
+        assert [shape for _, shape, _, _ in eng.batch_log] == [(4, 1)]
+        assert eng.queue_depth() == 0
+
+    def test_sessions_backlog_seals_as_one_bucket(self):
+        from cryptography.hazmat.primitives.ciphers.aead import AESGCM
+        keys = [bytes((29 * s + 3 * i + 7) & 0xFF for i in range(16))
+                for s in range(12)]
+        eng = _engine(aead_keys=keys)
+        recs = [(bytes([s + 1]) * 12, bytes([0x50 + s]) * 17,
+                 b"\x17\x03\x03\x00\x21", s) for s in range(12)]
+        reqs = [eng.submit(encode_aead_record(*r), op="gcm_seal")
+                for r in recs]
+        assert eng.run_once() == 12
+        assert [shape for _, shape, _, _ in eng.batch_log] == [(16, 17, 5)]
+        assert telemetry.counter("serve_padded_lanes") == 4
+        for req, (nonce, pt, aad, s) in zip(reqs, recs):
+            assert req.result(timeout=0) == AESGCM(keys[s]).encrypt(
+                nonce, pt, aad)
+
+
 class TestAdmission:
     def test_overload_sheds_with_typed_rejection(self):
         eng = _engine(max_queue=2)
